@@ -206,6 +206,7 @@ def measured_rows(sizes=None, device_counts=(8,)):
                                    device_counts=list(device_counts))
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # CPU host devices; never the chip
     proc = subprocess.run([sys.executable, "-c", code],
                           capture_output=True, text=True, timeout=900,
                           env=env)
@@ -270,6 +271,7 @@ def measured_multiaxis_rows(sizes=None, meshes=None):
         meshes=meshes, strategies=MULTIAXIS_STRATEGIES)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # CPU host devices; never the chip
     proc = subprocess.run([sys.executable, "-c", code],
                           capture_output=True, text=True, timeout=900,
                           env=env)
@@ -364,6 +366,7 @@ def measured_fused_rows(cells=None, p=FUSED_P, codecs=None,
         codecs=codecs, strategies=strategies, reps=reps)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # CPU host devices; never the chip
     proc = subprocess.run([sys.executable, "-c", code],
                           capture_output=True, text=True, timeout=1800,
                           env=env)
@@ -425,6 +428,7 @@ def measured_codec_rows(sizes=None, p=CODEC_P, codecs=None,
         codecs=codecs, strategies=strategies)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # CPU host devices; never the chip
     proc = subprocess.run([sys.executable, "-c", code],
                           capture_output=True, text=True, timeout=1800,
                           env=env)

@@ -72,6 +72,7 @@ def run(csv=True):
                                        "src"))
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # CPU host devices; never the chip
     proc = subprocess.run(
         [sys.executable, "-c", _SNIPPET.format(src=src)],
         capture_output=True, text=True, timeout=1800, env=env)

@@ -4,8 +4,8 @@ The paper's lineage (MVAPICH2 tuning tables, Shi et al.'s optimal
 trees) treats a collective schedule as something checkable against an
 analytic model *before* it runs.  PR 5 made our schedule a first-class
 IR (core/schedule.py); this package makes it model-checkable at any
-scale — including the 512-device production meshes the legacy-jax
-executor refuses (compat.PARTIAL_AUTO_MAX_DEVICES) — with three layers
+scale — including 512-device production meshes no host run can
+execute — with three layers
 (DESIGN.md §3.9):
 
 ``verify``       rule engine over :class:`repro.core.schedule

@@ -6,8 +6,8 @@ Modes (default = ``--source --schedules``):
 ``--schedules``       verify every registered config × design × mesh
                       cell from ``experiments/matrix.analysis_cells``
                       (SV rules) — including the 512-device and
-                      composed two-level schedules the executor cannot
-                      run on legacy jax.
+                      composed two-level schedules no host run can
+                      execute.
 ``--schedule-json F`` verify one serialized ReduceSchedule
                       (``repro/schedule/v1`` JSON, as written by
                       dryrun records or ``to_json``).

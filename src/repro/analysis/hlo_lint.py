@@ -92,7 +92,7 @@ def wire_check(sched, collective_bytes, rel_tol: float = 0.02) -> dict:
     payload, ``ps_gather`` → all-gather) and the bytes it charges
     (``Stage.hlo_bytes``).  The charged side may legitimately exceed
     the prediction (model-axis GSPMD collectives, padding on
-    non-divisible chunks, old-jax degraded-mode emulation), so the
+    non-divisible chunks), so the
     verdict is per kind: ``consistent`` = every predicted kind is
     within ``rel_tol`` below the charge it explains or lower.
     """

@@ -51,8 +51,7 @@ but only ever checked by executing on small meshes:
 All rules run on detached schedules (``plan=None``); the rules that
 need the leaf layout (SV003 leaf-gap, SV004 monotonicity, SV005)
 degrade to the checks the available metadata supports.  This is what
-lets a 512-device three-axis schedule — which the legacy-jax executor
-refuses outright — be verified without running it.
+lets a 512-device three-axis schedule be verified without running it.
 """
 from __future__ import annotations
 

@@ -1,302 +1,62 @@
-"""JAX version compatibility shims.
+"""The single home of the repo's manual-axis collectives and shard_map.
 
-The repo targets the modern manual-axes API (``jax.shard_map`` with
-``axis_names=``/``check_vma=``, ``jax.make_mesh(..., axis_types=...)``,
-``lax.axis_size``); CI and the baked container currently run jax 0.4.x
-where those spellings do not exist yet.  Every call site goes through
-this module so the rest of the codebase is written once, against the
-new API, and keeps working on both sides:
+Every other module reaches ``jax.shard_map``, the mesh constructor and
+the manual-axis ``lax`` collectives through these wrappers, so one file
+states how the repo uses them; the compat lint (CL001/CL002,
+``analysis/compat_lint.py``) keeps direct uses out of the rest of the
+tree.
 
 ``shard_map(f, mesh, in_specs, out_specs, axis_names, check_vma)``
-    New jax: forwarded verbatim.  Old jax: ``axis_names`` (the MANUAL
-    axes) is translated to the legacy ``auto=`` complement set and
-    ``check_vma`` to ``check_rep``.
+    ``jax.shard_map``; ``axis_names`` is the set of MANUAL axes
+    (``None``: every mesh axis is manual).  A strict subset leaves the
+    remaining axes to GSPMD (the ``legacy_partial_auto`` train lowering).
 
 ``make_mesh(shape, axis_names)``
-    Drops ``axis_types`` on old jax (all axes were implicitly Auto
-    there, which is exactly what every call site requests).
-
-``axis_size(axis)``
-    ``lax.axis_size`` where available; otherwise ``lax.psum(1, axis)``,
-    which jax constant-folds to a Python int inside shard_map (no
-    communication is emitted), so it remains usable in Python control
-    flow for building static ppermute schedules.
-
-Partial-auto degraded mode (old jax only)
------------------------------------------
-Old jax's partial-auto shard_map (manual data axes + GSPMD model axis)
-can only lower ``psum``: ``axis_index`` emits an unsupported
-PartitionId and ``ppermute``/``all_gather`` hit a fatal SPMD-partitioner
-check.  When :func:`shard_map` detects that combination it enters a
-degraded mode for the region: the per-axis rank is plumbed in as a
-hidden sharded argument (an ``arange(p)`` under ``P(axis)`` — each
-shard sees exactly its own index), and :func:`ppermute` /
-:func:`all_gather` are emulated with a one-hot expansion + ``psum``.
-Semantics are identical; wire cost is p·N instead of the algorithm's
-schedule, so the degraded mode is strictly a correctness fallback for
-the old-jax CPU test environment — on new jax every collective lowers
-natively and the compiled HLO is the schedule we wrote.  Full-manual
-regions (all mesh axes manual) never degrade on any version.
-
-Since the full-manual lowering path (DESIGN.md §3.12) removed every
-production use of partial-auto, the degraded mode is opt-in: legacy
-partial-auto raises :class:`PartialAutoUnsupported` unless the caller
-passes ``allow_degraded_partial_auto=True``, and even then only meshes
-up to ``PARTIAL_AUTO_MAX_DEVICES`` devices are accepted.
+    ``jax.make_mesh`` with every axis Auto, which is what every call
+    site requests.
 """
 from __future__ import annotations
 
-import contextlib
-import inspect
-import threading
-from typing import Any
-
 import jax
-import jax.numpy as jnp
-import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
-
-
-def _new_shard_map_params() -> frozenset:
-    """Keyword names of ``jax.shard_map`` if it exists AND speaks the new
-    dialect (``check_vma``); attribute presence alone is not enough —
-    intermediate jax versions exposed ``jax.shard_map`` with the legacy
-    ``check_rep`` signature."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        return frozenset()
-    try:
-        return frozenset(inspect.signature(fn).parameters)
-    except (TypeError, ValueError):
-        return frozenset()
-
-
-_NEW_SHARD_MAP_PARAMS = _new_shard_map_params()
-_HAS_NEW_SHARD_MAP = "check_vma" in _NEW_SHARD_MAP_PARAMS
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-_HAS_LAX_AXIS_SIZE = hasattr(lax, "axis_size")
-
-_degraded = threading.local()
-
-# Largest mesh the old-jax partial-auto degraded mode is validated on
-# (multidev checks run it up to 12 devices; 32 leaves headroom for
-# host-mesh experiments).  Beyond this, legacy partial-auto lowering is
-# known to die inside XLA's SPMD partitioner with a FATAL C++ check —
-#     F xla/hlo/utils/hlo_sharding_util.cc: Check failed:
-#     sharding.IsManualSubgroup()
-# — a process abort no Python try/except can catch (observed on every
-# train-shape dry-run on the 256/512-device production meshes), and the
-# one-hot psum emulation's p·N wire cost would be prohibitive there
-# anyway.  We refuse up front with an actionable error instead.
-PARTIAL_AUTO_MAX_DEVICES = 32
-
-
-class PartialAutoUnsupported(RuntimeError):
-    """Partial-auto ``shard_map`` on legacy jax over a mesh larger than
-    the validated degraded-mode scale (see PARTIAL_AUTO_MAX_DEVICES)."""
-
-
-def _degraded_idx(axis):
-    """Traced rank of ``axis`` if inside a degraded region, else None."""
-    table = getattr(_degraded, "idx", None)
-    if table is None:
-        return None
-    return table.get(axis)
-
-
-@contextlib.contextmanager
-def _degraded_region(idx_table):
-    prev = getattr(_degraded, "idx", None)
-    _degraded.idx = dict(prev or {}, **idx_table)
-    try:
-        yield
-    finally:
-        _degraded.idx = prev
 
 
 def axis_size(axis) -> int:
     """Static size of a manual mesh axis (Python int inside shard_map)."""
-    if _HAS_LAX_AXIS_SIZE:
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    return lax.axis_size(axis)
 
 
 def axis_index(axis):
-    """``lax.axis_index``, or the plumbed rank in a degraded region."""
-    idx = _degraded_idx(axis)
-    if idx is not None:
-        return idx
     return lax.axis_index(axis)
 
 
-def _onehot_gather(x, axis):
-    """(p,)+x.shape gather of ``x`` over ``axis`` built from psum: each
-    device scatters its shard into its own row of a zero block, psum
-    materializes the full stack everywhere."""
-    idx = _degraded_idx(axis)
-    p = axis_size(axis)
-    block = jnp.zeros((p,) + x.shape, x.dtype).at[idx].set(x)
-    return lax.psum(block, axis)
-
-
 def ppermute(x, axis, perm):
-    """``lax.ppermute``; emulated via psum inside a degraded region
-    (non-targets still receive zeros, matching ppermute semantics)."""
-    if _degraded_idx(axis) is None:
-        return lax.ppermute(x, axis, perm)
-    p = axis_size(axis)
-    src_for = np.full(p, -1, np.int64)
-    for s, d in perm:
-        src_for[d] = s
-    gathered = _onehot_gather(x, axis)
-    idx = _degraded_idx(axis)
-    src = jnp.asarray(np.where(src_for >= 0, src_for, 0), jnp.int32)[idx]
-    has_src = jnp.asarray(src_for >= 0)[idx]
-    recv = gathered[src]
-    return jnp.where(has_src, recv, jnp.zeros_like(recv))
+    return lax.ppermute(x, axis, perm)
 
 
 def all_gather(x, axis):
-    """``lax.all_gather`` (stacked, tiled=False); psum-emulated inside a
-    degraded region."""
-    if _degraded_idx(axis) is None:
-        return lax.all_gather(x, axis)
-    return _onehot_gather(x, axis)
+    """``lax.all_gather`` (stacked, tiled=False)."""
+    return lax.all_gather(x, axis)
 
 
 def psum(x, axis):
-    """``lax.psum`` over one axis or a tuple of axes.
-
-    Inside a degraded region the raw operand may carry a GSPMD-chosen
-    auto-axis sharding that the old partitioner cannot combine with a
-    manual-subgroup all-reduce (fatal ``IsManualSubgroup`` check); the
-    one-hot gather + local sum sidesteps it because the scattered block
-    starts from cleanly-replicated zeros."""
-    axes = (axis,) if isinstance(axis, str) else tuple(axis)
-    if all(_degraded_idx(ax) is None for ax in axes):
-        return lax.psum(x, axis)
-    for ax in axes:
-        if _degraded_idx(ax) is None:
-            x = lax.psum(x, ax)
-        else:
-            x = _onehot_gather(x, ax).sum(0)
-    return x
-
-
-@contextlib.contextmanager
-def use_mesh(mesh):
-    """Ambient/context mesh so bare-``PartitionSpec`` sharding
-    constraints resolve: ``jax.sharding.use_mesh``/``jax.set_mesh`` on
-    new jax, the ``Mesh`` context manager (resource env) on old jax."""
-    if hasattr(jax.sharding, "use_mesh"):
-        with jax.sharding.use_mesh(mesh):
-            yield
-    elif hasattr(jax, "set_mesh"):
-        ctx = jax.set_mesh(mesh)
-        if hasattr(ctx, "__enter__"):
-            with ctx:
-                yield
-        else:
-            yield
-    else:
-        with mesh:
-            yield
+    """``lax.psum`` over one axis or a tuple of axes."""
+    return lax.psum(x, axis)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with every axis Auto, on any jax version."""
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            axis_shapes, axis_names, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_shapes))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_shapes))
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-              check_vma: bool = False,
-              allow_degraded_partial_auto: bool = False):
-    """Version-portable ``shard_map``.
-
-    ``axis_names``: the set of MANUAL axes (new-API semantics).  ``None``
-    means all mesh axes are manual.
-
-    ``allow_degraded_partial_auto``: on legacy jax, partial-auto regions
-    (``axis_names`` a strict subset of the mesh axes) only lower through
-    the psum-emulation degraded mode (module docstring), which is a
-    correctness fallback — p*N wire cost, validated only up to
-    ``PARTIAL_AUTO_MAX_DEVICES`` devices.  Since the full-manual lowering
-    path landed (DESIGN.md §3.12) no production call site needs it, so it
-    is opt-in: without this flag a legacy partial-auto region raises
-    ``PartialAutoUnsupported`` at ANY device count instead of silently
-    degrading.  New jax ignores the flag (native lowering is exact).
-    """
-    if _HAS_NEW_SHARD_MAP:
-        kwargs: dict[str, Any] = dict(mesh=mesh, in_specs=in_specs,
-                                      out_specs=out_specs,
-                                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _legacy
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    if not auto:
-        return _legacy(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=check_vma)
-
-    # Partial-auto on old jax: enter degraded mode (see module docstring).
-    # PartitionSpec is a tuple subclass, so a bare P(...) must be treated
-    # as a single-argument spec, not unpacked into per-argument specs.
-    n_devices = int(mesh.devices.size)
-    if not allow_degraded_partial_auto:
-        raise PartialAutoUnsupported(
-            f"partial-auto shard_map (manual axes "
-            f"{sorted(set(mesh.axis_names) - auto)}, auto axes "
-            f"{sorted(auto)}) on this jax version ({jax.__version__}) "
-            f"only lowers through the degraded psum-emulation fallback "
-            f"(p*N wire cost; native legacy lowering aborts the PROCESS "
-            f"inside XLA's SPMD partitioner with a fatal 'Check failed: "
-            f"sharding.IsManualSubgroup()'), which is opt-in since the "
-            f"full-manual lowering path landed. Make every mesh axis "
-            f"manual instead (axis_names=None, DESIGN.md §3.12), upgrade "
-            f"to a jax with the new jax.shard_map(check_vma=...) API, or "
-            f"pass allow_degraded_partial_auto=True to accept the "
-            f"degraded fallback on a <= {PARTIAL_AUTO_MAX_DEVICES}-device "
-            f"host mesh (DESIGN.md §3.7 known-limit registry).")
-    if n_devices > PARTIAL_AUTO_MAX_DEVICES:
-        raise PartialAutoUnsupported(
-            f"partial-auto shard_map (manual axes "
-            f"{sorted(set(mesh.axis_names) - auto)}, auto axes "
-            f"{sorted(auto)}) on a {n_devices}-device mesh is not "
-            f"supported on this jax version ({jax.__version__}): legacy "
-            f"lowering aborts the PROCESS inside XLA's SPMD partitioner "
-            f"(fatal 'Check failed: sharding.IsManualSubgroup()', "
-            f"hlo_sharding_util.cc), and the psum-emulation fallback is "
-            f"validated only up to {PARTIAL_AUTO_MAX_DEVICES} devices. "
-            f"Upgrade to a jax with the new jax.shard_map(check_vma=...) "
-            f"API for native partial-auto lowering, or run this config "
-            f"on a <= {PARTIAL_AUTO_MAX_DEVICES}-device host mesh "
-            f"(DESIGN.md §3.7 known-limit registry).")
-    manual = tuple(ax for ax in mesh.axis_names if ax not in auto)
-    single_arg = not isinstance(in_specs, tuple) or isinstance(in_specs, P)
-    specs = (in_specs,) if single_arg else in_specs
-
-    def wrapped(idx_args, *args):
-        table = {ax: arr[0] for ax, arr in zip(manual, idx_args)}
-        with _degraded_region(table):
-            return f(*args)
-
-    inner = _legacy(wrapped, mesh=mesh,
-                    in_specs=(tuple(P(ax) for ax in manual),) + specs,
-                    out_specs=out_specs, check_rep=check_vma, auto=auto)
-
-    def outer(*args):
-        if single_arg and len(args) != 1:
-            raise TypeError("shard_map wrapper expected a single argument")
-        idx_args = tuple(
-            jnp.arange(mesh.shape[ax], dtype=jnp.int32) for ax in manual)
-        return inner(idx_args, *args)
-
-    return outer
+              check_vma: bool = False):
+    """``jax.shard_map`` with ``axis_names`` the set of MANUAL axes
+    (``None`` means all mesh axes are manual)."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)

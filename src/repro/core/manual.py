@@ -1,10 +1,8 @@
 """Full-manual model-axis lowering (DESIGN.md §3.12).
 
-Legacy jax cannot lower partial-auto ``shard_map`` (manual data axes +
-GSPMD ``model`` axis) past ``compat.PARTIAL_AUTO_MAX_DEVICES`` — the
-SPMD partitioner dies on a fatal ``IsManualSubgroup`` check.  Full-manual
-regions never degrade on any jax version, so the train/serve steps make
-the ``model`` axis manual too: parameters enter the region shard-shaped
+The train/serve steps make the ``model`` axis manual as well as the
+data axes, so the compiled program carries exactly the collectives the
+ReduceSchedule names: parameters enter the region shard-shaped
 (per-leaf specs restricted to the model axis, derived from
 ``models.param_pspecs``) and a differentiable gather boundary
 reconstructs the full tensors inside the region.

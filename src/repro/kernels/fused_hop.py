@@ -141,9 +141,15 @@ def _elemwise(kernel, out_dtype, flat, n, grid, block, interpret,
 # Kernel bodies
 # ---------------------------------------------------------------------------
 
+# One grid step's partial max, broadcast over a whole (8, 128) vreg
+# tile: Mosaic only accepts output blocks aligned to the TPU tiling, and
+# a (1,)-per-step block is not.
+_PARTIAL_TILE = (1, 8, 128)
+
+
 def _absmax_kernel(x_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
-    o_ref[...] = jnp.max(jnp.abs(x)).reshape((1,))
+    o_ref[...] = jnp.full(_PARTIAL_TILE, jnp.max(jnp.abs(x)))
 
 
 def _bf16_encode_kernel(x_ref, o_ref):
@@ -199,15 +205,16 @@ def hop_absmax(x: jax.Array, *, block_n: int = 2048,
     if _direct(interpret):
         o = _HostRef(dtype=jnp.float32)
         _absmax_kernel(_HostRef(x.reshape(-1)), o)
-        return o.val[0]
+        return o.val[0, 0, 0]
     interpret = resolve_interpret(interpret)
     flat, _, grid, block = _tile(x, block_n, interpret)
     partial = pl.pallas_call(
         _absmax_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(grid, jnp.float32),
+        out_specs=pl.BlockSpec(_PARTIAL_TILE, lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (grid[0],) + _PARTIAL_TILE[1:], jnp.float32),
         interpret=interpret,
     )(flat)
     return jnp.max(partial)

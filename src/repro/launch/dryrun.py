@@ -1,11 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# Compiles on forced CPU host devices; never takes an accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For one (arch × input-shape × mesh) combination this script:
   1. builds the production mesh ((16,16) or (2,16,16) = 512 placeholder
-     host devices — hence the XLA_FLAGS line ABOVE ALL OTHER IMPORTS),
+     host devices — hence the XLA_FLAGS and JAX_PLATFORMS lines ABOVE
+     ALL OTHER IMPORTS),
   2. lowers + COMPILES the appropriate step (train_step for train_4k,
      prefill for prefill_32k, serve_step for decode shapes) with full
      production shardings over ShapeDtypeStructs (no allocation),
@@ -148,49 +151,6 @@ def _schedule_record(agg, mesh, dp_axes, params_struct, roof,
     }
 
 
-def _static_verify(arch: str, shape_name: str, mesh, strategy: str,
-                   fusion_mb: float, sharding_aware: bool,
-                   remat: bool = False, wire_dtype: str = "",
-                   spec_overrides=None, selector_mode: str = "analytic",
-                   selector_table: str = "", overlap: bool = False,
-                   codec: str = "", error_feedback: bool = False) -> dict:
-    """Resolve the config's ReduceSchedule WITHOUT lowering or
-    compiling and run the static verifier (repro.analysis) over it —
-    the path that proves a >32-device schedule sound even though
-    legacy jax refuses to execute it (PARTIAL_AUTO_MAX_DEVICES)."""
-    import dataclasses
-
-    import jax
-    from repro.analysis import verify as analysis_verify
-    from repro.configs import get_spec, spec_for_shape
-    from repro.core import AggregatorConfig, GradientAggregator
-    from repro.launch.mesh import dp_axes_of
-    from repro.models import build_model, param_groups
-
-    spec = spec_for_shape(get_spec(arch), shape_name)
-    if remat:
-        spec = dataclasses.replace(spec, remat=True)
-    if spec_overrides:
-        spec = dataclasses.replace(spec, **spec_overrides)
-    model = build_model(spec)
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    dp_axes = dp_axes_of(mesh)
-    agg = GradientAggregator(
-        AggregatorConfig(strategy=strategy,
-                         fusion_threshold_mb=fusion_mb,
-                         sharding_aware=sharding_aware,
-                         wire_dtype=wire_dtype,
-                         selector_mode=selector_mode,
-                         selector_table=selector_table,
-                         overlap=overlap, codec=codec,
-                         error_feedback=error_feedback), dp_axes)
-    axis_sizes = tuple(int(mesh.shape[a]) for a in dp_axes)
-    sched = agg.resolve(params, axis_sizes,
-                        groups=param_groups(params))
-    return analysis_verify.verify_summary(
-        sched, context=f"{arch}/{shape_name}")
-
-
 def _attach_trace(rec: dict, arch: str, shape_name: str, mesh,
                   strategy: str, fusion_mb: float, sharding_aware: bool,
                   remat: bool, wire_dtype: str, spec_overrides,
@@ -202,12 +162,7 @@ def _attach_trace(rec: dict, arch: str, shape_name: str, mesh,
     through the measured probe (repro.telemetry.closure — each distinct
     stage as its own jitted collective on an axis_size submesh of the
     dry-run's forced host devices), attach the per-stage residual table
-    + metrics snapshot to the record and write the Perfetto trace.
-
-    Works on SKIP records too: the schedule resolves without lowering
-    (the same path _static_verify uses), so even configs the executor
-    refuses (>32-device partial-auto) get measured per-stage replays at
-    production payload sizes."""
+    + metrics snapshot to the record and write the Perfetto trace."""
     import dataclasses
 
     import jax
@@ -294,7 +249,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
             legacy_partial_auto: bool = False) -> dict:
     import jax
     from repro.configs import SHAPES, get_spec, shape_supported
-    from repro.core.compat import use_mesh
     from repro.launch import roofline as rl
     from repro.launch.mesh import make_production_mesh
 
@@ -316,7 +270,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.perf_counter()
     try:
         # context mesh so bare-P sharding constraints resolve
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             step, args, aux = _build_step(arch, shape_name, mesh, strategy,
                                           fusion_mb, sharding_aware,
                                           remat=remat,
@@ -335,8 +289,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
 
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):   # old jax: per-device list
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
             from repro.launch import hlo_analysis as ha
             agg = ha.analyze(hlo)
@@ -412,43 +364,12 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
                           f"overlapped (exposed "
                           f"{ov['exposed_comm_s']*1e3:.2f}ms)")
     except Exception as e:  # noqa: BLE001 — recorded, not swallowed
-        from repro.core.compat import PartialAutoUnsupported
-        if isinstance(e, PartialAutoUnsupported):
-            # Environment limitation, not a config error: the guard in
-            # core/compat.py turned what used to be a fatal XLA process
-            # abort (IsManualSubgroup) into a clean, recorded skip —
-            # pinned by tests/test_partial_auto_guard.py.
-            rec.update(status="SKIP", reason=str(e))
-            # The schedule is still fully resolvable without lowering:
-            # run the static verifier over the IR so the record proves
-            # soundness at a scale the executor cannot reach.
-            try:
-                analysis = _static_verify(
-                    arch, shape_name, mesh, strategy, fusion_mb,
-                    sharding_aware, remat=remat, wire_dtype=wire_dtype,
-                    spec_overrides=spec_overrides,
-                    selector_mode=selector_mode,
-                    selector_table=selector_table, overlap=overlap,
-                    codec=codec, error_feedback=error_feedback)
-                rec["analysis"] = analysis
-                rec["verified_static"] = analysis["n_errors"] == 0
-            except Exception as ve:  # noqa: BLE001 — recorded, not raised
-                rec["verified_static"] = False
-                rec["analysis"] = {"error":
-                                   f"{type(ve).__name__}: {ve}"}
-            if verbose:
-                mark = "statically verified" \
-                    if rec.get("verified_static") else "unverified"
-                print(f"[dryrun] {arch} × {shape_name} × {rec['mesh']}: "
-                      f"SKIP (partial-auto unsupported on this jax; "
-                      f"schedule {mark})")
-        else:
-            rec.update(status="FAIL", error=f"{type(e).__name__}: {e}",
-                       traceback=traceback.format_exc()[-4000:])
-            if verbose:
-                print(f"[dryrun] {arch} × {shape_name} × {rec['mesh']}: "
-                      f"FAIL {e}")
-    if trace_path and rec["status"] in ("OK", "SKIP"):
+        rec.update(status="FAIL", error=f"{type(e).__name__}: {e}",
+                   traceback=traceback.format_exc()[-4000:])
+        if verbose:
+            print(f"[dryrun] {arch} × {shape_name} × {rec['mesh']}: "
+                  f"FAIL {e}")
+    if trace_path and rec["status"] == "OK":
         try:
             _attach_trace(rec, arch, shape_name, mesh, strategy,
                           fusion_mb, sharding_aware, remat, wire_dtype,
@@ -492,13 +413,10 @@ def main():
                          "step (requires --codec)")
     ap.add_argument("--seq-parallel", action="store_true")
     ap.add_argument("--legacy-partial-auto", action="store_true",
-                    help="opt back into the pre-§3.12 partial-auto "
-                         "lowering (model axis AUTO under GSPMD): on "
-                         "legacy jax this degrades to psum emulation and "
-                         "is refused beyond compat.PARTIAL_AUTO_MAX_"
-                         "DEVICES (recorded as a statically-verified "
-                         "SKIP).  Default is the full-manual path, "
-                         "which compiles at any device count.")
+                    help="lower the model axis AUTO under GSPMD "
+                         "(partial-auto shard_map, the lowering "
+                         "seq_parallel needs) instead of the default "
+                         "full-manual region")
     ap.add_argument("--override", action="append", default=[],
                     help="spec override k=v (int/float/bool literal)")
     ap.add_argument("--json")

@@ -6,6 +6,10 @@ jax init).
 """
 from __future__ import annotations
 
+import os
+
+import jax
+
 from repro.core.compat import make_mesh
 
 
@@ -22,6 +26,27 @@ def make_host_mesh(data: int = 4, model: int = 2, pods: int = 0):
     if pods:
         return make_mesh((pods, data, model), ("pod", "data", "model"))
     return make_mesh((data, model), ("data", "model"))
+
+
+def use_host_devices(n: int) -> None:
+    """Run this process on ``n`` forced CPU host devices.  Call before
+    JAX initializes a backend."""
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={n}").strip()
+    jax.config.update("jax_platforms", "cpu")
+
+
+def mesh_from_arg(text: str):
+    """Mesh for a launcher's ``--mesh``: ``"DxM"`` or ``"PxDxM"``;
+    ``""`` gives (data=every device present, model=1)."""
+    dims = (tuple(int(x) for x in text.split("x")) if text
+            else (len(jax.devices()), 1))
+    if len(dims) not in (2, 3):
+        raise ValueError(f"--mesh {text!r}: want DxM or PxDxM")
+    if len(dims) == 2:
+        return make_host_mesh(data=dims[0], model=dims[1])
+    return make_host_mesh(pods=dims[0], data=dims[1], model=dims[2])
 
 
 def dp_axes_of(mesh) -> tuple:

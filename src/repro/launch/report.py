@@ -58,10 +58,7 @@ def dryrun_matrix(recs, mesh):
             if r is None:
                 cells.append("—")
             elif r["status"] == "SKIP":
-                # ✓ = the unexecutable schedule was still statically
-                # verified (repro.analysis, zero error diagnostics)
-                cells.append("SKIP†✓" if r.get("verified_static")
-                             else "SKIP†")
+                cells.append("SKIP†")
             elif r["status"] != "OK":
                 cells.append(f"**{r['status']}**")
             else:

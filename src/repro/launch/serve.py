@@ -1,12 +1,24 @@
 """Serving driver: batched greedy decode of synthetic prompts.
 
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
-        --batch 4 --prompt-len 16 --new-tokens 32 --mesh 4x2
+        --batch 4 --prompt-len 16 --new-tokens 32
+
+The mesh defaults to (data=<devices present>, model=1); ``--host-devices
+N`` runs on N forced CPU host devices instead.
 """
 import argparse
-import os
 import sys
 import time
+
+import jax
+
+from repro.configs import get_spec
+from repro.data.synthetic import SyntheticText, extra_inputs
+from repro.launch.mesh import dp_axes_of, mesh_from_arg, use_host_devices
+from repro.launch.runtime import configure_compile_cache, device_line
+from repro.models import build_model
+from repro.serve import ServeEngine
+from repro.serve.engine import ServeConfig
 
 
 def main():
@@ -15,30 +27,20 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=32)
-    ap.add_argument("--mesh", default="4x2")
+    ap.add_argument("--mesh", default="",
+                    help="DxM or PxDxM (default: data over every "
+                         "device, model=1)")
+    ap.add_argument("--host-devices", type=int, default=0,
+                    help="run on this many forced CPU host devices")
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    dims = [int(x) for x in args.mesh.split("x")]
-    need = 1
-    for d in dims:
-        need *= d
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={need}")
-
-    import jax
-    from repro.configs import get_spec
-    from repro.data.synthetic import SyntheticText, extra_inputs
-    from repro.launch.mesh import dp_axes_of, make_host_mesh
-    from repro.models import build_model
-    from repro.serve import ServeEngine
-    from repro.serve.engine import ServeConfig
-
-    if len(dims) == 2:
-        mesh = make_host_mesh(data=dims[0], model=dims[1])
-    else:
-        mesh = make_host_mesh(pods=dims[0], data=dims[1], model=dims[2])
+    if args.host_devices:
+        use_host_devices(args.host_devices)
+    print(device_line(), flush=True)
+    configure_compile_cache()
+    mesh = mesh_from_arg(args.mesh)
 
     spec = get_spec(args.arch)
     if not args.full:

@@ -33,6 +33,7 @@ def run_pair(out_dir, arch, shape, multi_pod, strategy="rhd_rsa",
     cmd.extend(extra_args)
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    env["JAX_PLATFORMS"] = "cpu"   # the dry run compiles on host devices
     t0 = time.time()
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,

@@ -1,16 +1,24 @@
 """End-to-end training driver.
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
-        --steps 200 --batch 8 --seq 128 --mesh 4x2 --strategy rhd_rsa
+        --steps 200 --batch 8 --seq 128 --strategy rhd_rsa
 
-On this host the mesh maps onto XLA host-platform devices (set
---host-devices); on a real TPU slice the same flags drive the production
-mesh. The model is the assigned architecture's REDUCED variant by default
-(--full for the real config — only sensible on real hardware).
+The mesh defaults to (data=<devices present>, model=1).  ``--host-devices
+N`` runs on N forced CPU host devices instead (e.g. ``--host-devices 8
+--mesh 4x2``).  The model is the assigned architecture's REDUCED variant
+by default (--full for the real config — only sensible on real hardware).
 """
 import argparse
-import os
 import sys
+
+from repro.configs import get_spec
+from repro.core import AggregatorConfig
+from repro.data.synthetic import SyntheticText, extra_inputs
+from repro.launch.mesh import dp_axes_of, mesh_from_arg, use_host_devices
+from repro.launch.runtime import configure_compile_cache, device_line
+from repro.models import build_model
+from repro.optim import adamw, cosine_warmup, sgd
+from repro.train import Trainer, TrainerConfig, TrainStepConfig
 
 
 def main():
@@ -19,9 +27,11 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--mesh", default="4x2",
-                    help="DxM or PxDxM, e.g. 4x2 or 2x2x2")
-    ap.add_argument("--host-devices", type=int, default=8)
+    ap.add_argument("--mesh", default="",
+                    help="DxM or PxDxM, e.g. 4x2 or 2x2x2 (default: "
+                         "data over every device, model=1)")
+    ap.add_argument("--host-devices", type=int, default=0,
+                    help="run on this many forced CPU host devices")
     ap.add_argument("--strategy", default="rhd_rsa")
     ap.add_argument("--fusion-mb", type=float, default=4.0)
     ap.add_argument("--no-fuse", action="store_true")
@@ -36,34 +46,18 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    dims = [int(x) for x in args.mesh.split("x")]
-    need = 1
-    for d in dims:
-        need *= d
-    os.environ.setdefault(
-        "XLA_FLAGS",
-        f"--xla_force_host_platform_device_count="
-        f"{max(args.host_devices, need)}")
-
-    import jax
-    from repro.configs import get_spec
-    from repro.core import AggregatorConfig
-    from repro.data.synthetic import SyntheticText, extra_inputs
-    from repro.launch.mesh import dp_axes_of, make_host_mesh
-    from repro.models import build_model
-    from repro.optim import adamw, cosine_warmup, sgd
-    from repro.train import Trainer, TrainerConfig, TrainStepConfig
-
-    if len(dims) == 2:
-        mesh = make_host_mesh(data=dims[0], model=dims[1])
-    else:
-        mesh = make_host_mesh(pods=dims[0], data=dims[1], model=dims[2])
+    if args.host_devices:
+        use_host_devices(args.host_devices)
+    print(device_line(), flush=True)
+    configure_compile_cache()
+    mesh = mesh_from_arg(args.mesh)
 
     spec = get_spec(args.arch)
     if not args.full:
         spec = spec.reduced()
     model = build_model(spec)
-    print(f"arch={spec.name} family={spec.family} mesh={args.mesh} "
+    print(f"arch={spec.name} family={spec.family} "
+          f"mesh={dict(mesh.shape)} "
           f"strategy={args.strategy}")
 
     data = SyntheticText(spec.vocab_size, batch=args.batch,

@@ -5,9 +5,7 @@ lowering as training (DESIGN.md §3.12) when the mesh carries a ``model``
 axis: parameters enter the region shard-shaped under the per-leaf specs
 of :func:`repro.core.manual.model_shard_specs` and the gather boundary
 reconstructs them before the forward — real tensor-parallel parameter
-sharding with every mesh axis manual, so legacy jax compiles it at any
-device count (the partial-auto path was capped at
-``compat.PARTIAL_AUTO_MAX_DEVICES``).  The KV cache stays REPLICATED
+sharding with every mesh axis manual.  The KV cache stays REPLICATED
 over the model axis inside the manual region (the gathered forward
 computes full per-layer tensors on every model rank); batch/tokens/
 logits shard over the data axes.  Meshes without a model axis — or

@@ -479,6 +479,7 @@ def _measure_cells_subprocess(reps: int) -> Dict[str, Dict[str, float]]:
                                       reps=reps)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # CPU host devices; never the chip
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", snippet], env=env,
                           capture_output=True, text=True, timeout=1800)

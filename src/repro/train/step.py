@@ -26,13 +26,10 @@ the region SHARD-shaped under per-leaf specs derived from
 reconstructs the full tensors for the loss, and its backward slices each
 cotangent back to the rank's shard — so model-sharded leaves dp-reduce
 at 1/m wire while replicated buckets carry the IR's three-level model
-bracket (``ring@data×rhd@pod×ag@model``).  Full-manual regions never
-degrade on legacy jax, which is what unlocks the 512-device production
-mesh past ``compat.PARTIAL_AUTO_MAX_DEVICES``.  The pre-§3.12 partial
--auto lowering (model axis AUTO under GSPMD) survives as the explicit
-``legacy_partial_auto`` opt-in — required for ``seq_parallel`` residual
-sharding, which only GSPMD can express — and on legacy jax is refused
-by ``compat.shard_map`` beyond 32 devices.
+bracket (``ring@data×rhd@pod×ag@model``).  The pre-§3.12 partial-auto
+lowering (model axis AUTO under GSPMD) survives as the explicit
+``legacy_partial_auto`` opt-in, which ``seq_parallel`` residual
+sharding requires, since only GSPMD can express it.
 
 Clipping order matters twice.  The seed clipped LOCAL grads by each
 rank's own shard norm before aggregation, which (a) is not synchronous
@@ -81,11 +78,10 @@ def make_train_step(model: ModelApi, optimizer: Optimizer,
     Returns (step_fn, shardings) where
     ``step_fn(params, opt_state, batch) -> (params, opt_state, metrics)``.
 
-    ``legacy_partial_auto``: opt back into the pre-§3.12 lowering (model
-    axis AUTO under GSPMD, degraded psum-emulation on legacy jax, hard
-    ceiling at ``compat.PARTIAL_AUTO_MAX_DEVICES`` there).  The default
-    full-manual path never degrades; ``seq_parallel`` specs force the
-    legacy path since their residual-stream sharding constraint is a
+    ``legacy_partial_auto``: lower the model axis AUTO under GSPMD (a
+    partial-auto ``shard_map`` with only the data axes manual) instead
+    of the default full-manual region.  ``seq_parallel`` specs force
+    this path since their residual-stream sharding constraint is a
     GSPMD annotation the manual region cannot express.
     """
     dp_axes = tuple(cfg.dp_axes)
@@ -145,8 +141,7 @@ def make_train_step(model: ModelApi, optimizer: Optimizer,
     bspecs = batch_pspecs(batch_example, dp_axes)
     if manual:
         # Full-manual region: params/opt state enter shard-shaped under
-        # the per-leaf model specs; every mesh axis is manual, so legacy
-        # jax takes the never-degrading branch at any device count.
+        # the per-leaf model specs; every mesh axis is manual.
         region_pspecs: Any = mspecs
         region_sspecs: Any = optimizer.state_pspecs(mspecs)
         region_axes = None
@@ -159,8 +154,7 @@ def make_train_step(model: ModelApi, optimizer: Optimizer,
         in_specs=(region_pspecs, region_sspecs, bspecs),
         out_specs=(region_pspecs, region_sspecs, P()),
         axis_names=region_axes,
-        check_vma=False,
-        allow_degraded_partial_auto=legacy_partial_auto)
+        check_vma=False)
 
     from repro.serve.step import sanitize_pspec
 

@@ -26,6 +26,7 @@ def force_host_devices(default: int) -> int:
                            "jax is imported")
     n = int(os.environ.get(ENV_VAR, default))
     os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    os.environ["JAX_PLATFORMS"] = "cpu"   # host devices; never the chip
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     if src not in sys.path:
         sys.path.insert(0, src)

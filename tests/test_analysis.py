@@ -15,8 +15,7 @@ Three fronts:
   equivalence with the roofline wrapper, interleave, mixed-dtype,
   unexpected-allreduce + baseline), compat_lint on violation fixtures
   and on the real source tree, and the CLI's exit-code contract
-  (non-zero on a mutated schedule JSON, zero on a clean one), plus the
-  512-device production-mesh dryrun gaining ``verified_static: true``.
+  (non-zero on a mutated schedule JSON, zero on a clean one).
 """
 import dataclasses
 import json
@@ -29,16 +28,11 @@ import pytest
 
 from repro.analysis import ERROR, WARN, Diagnostic, compat_lint, hlo_lint
 from repro.analysis import verify as av
-from repro.core import compat
 from repro.core import schedule as sm
 from repro.experiments import matrix
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-
-needs_legacy = pytest.mark.skipif(
-    compat._HAS_NEW_SHARD_MAP,
-    reason="new-jax shard_map lowers partial-auto natively — no guard")
 
 
 def rule_ids(sched):
@@ -495,41 +489,3 @@ def test_cli_source_mode_green_on_head(tmp_path):
     rec = json.loads(out.read_text())
     assert rec["schema"] == "repro/analysis/v1"
     assert rec["n_errors"] == 0
-
-
-# ---------------------------------------------------------------------------
-# the >32-device SKIP path: statically verified, not just refused
-# ---------------------------------------------------------------------------
-
-@needs_legacy
-@pytest.mark.timeout(420)
-def test_multipod_dryrun_skip_is_statically_verified(tmp_path):
-    """The 512-chip production-mesh record that previously only said
-    SKIP must now also prove the schedule sound: verified_static=True
-    with zero error diagnostics (ISSUE 6 acceptance).  Since the
-    full-manual lowering landed the SKIP path only exists under the
-    explicit --legacy-partial-auto opt-in (the default COMPILES this
-    mesh — pinned by test_partial_auto_guard.py and the CI
-    production-dryrun step)."""
-    out = tmp_path / "rec.json"
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.launch.dryrun", "--arch",
-         "smollm-360m", "--shape", "train_4k", "--multi-pod",
-         "--legacy-partial-auto", "--json", str(out)],
-        capture_output=True, text=True, timeout=400, env=env)
-    assert proc.returncode == 0, \
-        f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr[-3000:]}"
-    rec = json.loads(out.read_text())
-    assert rec["status"] == "SKIP"
-    assert rec["mesh"] == "2x16x16"
-    assert "IsManualSubgroup" in rec["reason"]
-    assert rec["verified_static"] is True
-    analysis = rec["analysis"]
-    assert analysis["n_errors"] == 0
-    assert analysis["schema"] == "repro/analysis/v1"
-    assert analysis["n_buckets"] > 0
-    # two dp axes of the multi-pod mesh: ("pod", "data") = (2, 16)
-    assert analysis["axis_sizes"] == [2, 16]

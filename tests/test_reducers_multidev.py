@@ -102,7 +102,7 @@ def test_multidev_three_axis_checks():
     bracket (DESIGN.md §3.12): ``ring@data×rhd@pod×ag@model`` bit-exact
     vs dp psum, HLO permute bytes == Σ per-stage IR wire bytes with
     wire_check PASS, and a real train step on the three-axis mesh
-    matching the ≤32-device degraded partial-auto opt-in."""
+    matching the GSPMD partial-auto lowering."""
     _run_checks("multidev_three_axis_checks.py", 8,
                 "ALL THREE-AXIS CHECKS PASSED")
 
