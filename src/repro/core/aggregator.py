@@ -42,6 +42,10 @@ from .compat import axis_size
 from .plan_cache import GLOBAL_EXECUTOR_CACHE, GLOBAL_PLAN_CACHE, PlanCache
 from .schedule import ReduceSchedule
 
+# The named scope over the aggregator's ops in a compiled step (both
+# placements); the benchmark's device-trace reduction reads it.
+SCOPE = "aggregate"
+
 
 def _chunk_axis(group, ndim: int) -> int:
     """First unsharded dim of a leaf whose fusion-group tag is its
@@ -242,13 +246,6 @@ class GradientAggregator:
             model_axis_size=int(model_axis_size or 1), cache=self.cache)
         self.last_schedule = sched
         if telemetry.enabled():
-            tracer = telemetry.get_tracer()
-            with tracer.span("aggregate.resolve", cat="trace",
-                             fingerprint=sched.fingerprint(),
-                             n_buckets=len(sched.buckets),
-                             strategy=cfg.strategy,
-                             placement=cfg.placement):
-                pass
             telemetry.metrics.record_schedule(sched)
             telemetry.record_plan_cache(self.cache)
             telemetry.record_executor_cache(GLOBAL_EXECUTOR_CACHE)
@@ -285,18 +282,9 @@ class GradientAggregator:
         quantizes ONCE on the whole fused buffer before the stage walk —
         the per-hop codec then transports an already-on-grid payload."""
         cfg = self.config
-        tracer = telemetry.get_tracer()
-        if tracer.enabled:
-            ctx = tracer.span(
-                bucket.path, cat="trace", ir_path=bucket.path,
-                strategy=bucket.strategy, size=bucket.size,
-                n_bytes=bucket.n_bytes, wire_bytes=bucket.wire_bytes,
-                readiness_rank=bucket.readiness_rank,
-                placement=cfg.placement,
-                error_feedback=residual is not None)
-        else:
-            ctx = tracer.span("")           # shared no-op
-        with ctx:
+        # The bucket's IR path as a scope: its ops carry
+        # ``.../bucket[i]/stage[j]/hop[k]`` in the compiled program.
+        with jax.named_scope(bucket.path):
             accum = jnp.dtype(cfg.wire_dtype or cfg.accum_dtype)
             orig = buf.dtype
             new_residual = None
@@ -356,16 +344,15 @@ class GradientAggregator:
         plan = sched.plan
         reduced = []
         new_residuals = []
-        bufs = plan.flatten(grads)
-        if residuals is not None and len(residuals) != len(bufs):
-            raise ValueError(
-                f"{len(residuals)} residual buffers for "
-                f"{len(bufs)} fusion buckets — pass init_residuals() "
-                f"output for these grads")
-        tracer = telemetry.get_tracer()
-        with tracer.span("aggregate", cat="trace",
-                         n_buckets=len(sched.buckets),
-                         placement=self.config.placement):
+        # Packing into the fused buffers and unpacking are the
+        # aggregator's own work: both sit inside its scope.
+        with jax.named_scope(SCOPE):
+            bufs = plan.flatten(grads)
+            if residuals is not None and len(residuals) != len(bufs):
+                raise ValueError(
+                    f"{len(residuals)} residual buffers for "
+                    f"{len(bufs)} fusion buckets — pass init_residuals() "
+                    f"output for these grads")
             for i, (bucket, buf) in enumerate(zip(sched.buckets, bufs)):
                 group = plan.buckets[bucket.index].group
                 if residuals is not None:
@@ -375,9 +362,10 @@ class GradientAggregator:
                 else:
                     out = self._reduce_buffer(bucket, group, buf, scale)
                 reduced.append(out)
+            grads = plan.unflatten(reduced)
         if residuals is not None:
-            return plan.unflatten(reduced), tuple(new_residuals)
-        return plan.unflatten(reduced)
+            return grads, tuple(new_residuals)
+        return grads
 
     # -- overlapped (in-backward) path --------------------------------------
 
@@ -396,11 +384,12 @@ class GradientAggregator:
             return leaves, None
 
         def bwd(_, cts):
-            buf = plan.flatten_bucket(plan.buckets[bucket.index],
-                                      list(cts))
-            buf = self._reduce_buffer(bucket, group, buf, scale)
-            return tuple(plan.unflatten_bucket(
-                plan.buckets[bucket.index], buf))
+            with jax.named_scope(SCOPE):
+                buf = plan.flatten_bucket(plan.buckets[bucket.index],
+                                          list(cts))
+                buf = self._reduce_buffer(bucket, group, buf, scale)
+                return tuple(plan.unflatten_bucket(
+                    plan.buckets[bucket.index], buf))
 
         boundary.defvjp(fwd, bwd)
         return boundary
@@ -427,19 +416,12 @@ class GradientAggregator:
         sched, scale = self._trace_context(params, groups)
         flat, treedef = jax.tree_util.tree_flatten(params)
         out = list(flat)
-        tracer = telemetry.get_tracer()
-        # The per-bucket spans fire later, when jax traces the BACKWARD
-        # (each custom_vjp bwd rule runs _reduce_buffer); this span only
-        # records the wrap order at forward-trace time.
-        with tracer.span("overlap_params", cat="trace",
-                         n_buckets=len(sched.buckets),
-                         readiness_order=list(sched.readiness_order())):
-            for bi in sched.readiness_order():
-                bucket = sched.buckets[bi]
-                boundary = self._bucket_boundary(sched, bucket, scale)
-                wrapped = boundary(*[flat[i] for i in bucket.leaf_indices])
-                for i, leaf in zip(bucket.leaf_indices, wrapped):
-                    out[i] = leaf
+        for bi in sched.readiness_order():
+            bucket = sched.buckets[bi]
+            boundary = self._bucket_boundary(sched, bucket, scale)
+            wrapped = boundary(*[flat[i] for i in bucket.leaf_indices])
+            for i, leaf in zip(bucket.leaf_indices, wrapped):
+                out[i] = leaf
         return jax.tree_util.tree_unflatten(treedef, out)
 
     # -- scalars (loss/metrics) ---------------------------------------------

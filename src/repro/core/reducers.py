@@ -35,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.telemetry import trace as telemetry_trace
-
 from . import compat
 from .compat import all_gather, axis_index, axis_size, ppermute
 
@@ -320,28 +318,23 @@ def _stage_permute(st):
     return codec_mod.permuter(cname, fused=fused)
 
 
-def _traced_permute(tracer, inner, st, stage_path):
-    """Wrap a stage's hop primitive so every ppermute hop records a
-    telemetry span (``<stage_path>.hop[k]``) with its payload bytes.
-    For codec'd stages ``inner`` is the encode→permute→decode wrapper,
-    so the hop span covers the codec encode/decode as well.  Spans are
-    host-side metadata only — the traced computation is untouched
-    (DESIGN.md §3.11 disabled-mode identity)."""
-    cname = getattr(st, "codec", "none") or "none"
+def _scoped_hops(inner):
+    """Wrap a stage's hop primitive so the ``k``-th ppermute hop of the
+    stage runs inside ``jax.named_scope("hop[k]")``: the compiled
+    program's ops carry ``.../stage[j]/hop[k]`` (metadata only).  For
+    codec'd stages ``inner`` is the encode→permute→decode wrapper, so
+    the hop scope covers the codec encode/decode as well."""
     counter = [0]
     inner_hop = _as_hop(inner)
 
     def permute(x, axis, perm, add=None):
         k = counter[0]
         counter[0] += 1
-        with tracer.span(f"hop[{k}]", cat="trace",
-                         ir_path=f"{stage_path}.hop[{k}]",
-                         payload_bytes=int(x.size) * x.dtype.itemsize,
-                         n_edges=len(perm), codec=cname):
+        with jax.named_scope(f"hop[{k}]"):
             return inner_hop(x, axis, perm, add=add)
 
-    # Preserve the hop protocol so the reducers keep the add fused
-    # into the (possibly fused) inner permuter rather than re-adding.
+    # Keep the hop protocol, so the reducers leave the add to the
+    # (possibly fused) inner permuter rather than adding it again.
     permute.supports_add = True
     return permute
 
@@ -374,33 +367,16 @@ def execute_stages(x: jax.Array, stages) -> jax.Array:
     orig_dtype = x.dtype
     if coded and x.dtype != jnp.float32:
         x = x.astype(jnp.float32)
-    tracer = telemetry_trace.get_tracer()
     pending: list = []                      # (axis, orig_len) stack
     for j, st in enumerate(stages):
         permute = _stage_permute(st)
-        if tracer.enabled:
-            # IR path = enclosing bucket span's path (opened by the
-            # aggregator) + this stage's index; bare stage lists (the
-            # micro-benchmarks) get "stage[j]" alone.
-            base = tracer.current_path()
-            path = f"{base}.stage[{j}]" if base else f"stage[{j}]"
-            ctx = tracer.span(
-                f"stage[{j}]", cat="trace", ir_path=path,
-                op=st.op, algorithm=st.algorithm, axis=st.axis,
-                axis_size=int(getattr(st, "axis_size", 0)),
-                n_bytes=int(getattr(st, "n_bytes", 0)),
-                wire_bytes=int(getattr(st, "wire_bytes", 0)),
-                hlo_kind=getattr(st, "hlo_kind", "") or "",
-                hlo_bytes=int(getattr(st, "hlo_bytes", 0)),
-                codec=getattr(st, "codec", "none") or "none")
-            # Only ppermute-hop algorithms take a permute override
-            # (psum/ps_gather have no explicit hops to wrap).
-            if st.op != "allreduce" or st.algorithm in ("ring_rsa",
-                                                        "rhd_rsa"):
-                permute = _traced_permute(tracer, permute, st, path)
-        else:
-            ctx = tracer.span("")           # shared no-op
-        with ctx:
+        # Only ppermute-hop algorithms take a permute override
+        # (psum/ps_gather have no explicit hops to scope).
+        if st.op != "allreduce" or st.algorithm in ("ring_rsa", "rhd_rsa"):
+            permute = _scoped_hops(permute)
+        # Under the aggregator's ``bucket[i]`` scope this names the IR
+        # path ``bucket[i].stage[j]`` in the compiled program.
+        with jax.named_scope(f"stage[{j}]"):
             if st.op == "reduce_scatter":
                 if st.algorithm != "ring_rsa":
                     raise ValueError(f"unknown reduce-scatter algorithm "

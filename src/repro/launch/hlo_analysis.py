@@ -337,3 +337,68 @@ def analyze(text: str) -> Aggregate:
     # note: fused-computation flops are also reachable directly; memoized
     # analysis from entry only visits what executes.
     return analyze_computation(comps, entry, memo)
+
+
+# -- metadata -----------------------------------------------------------
+
+_METADATA = re.compile(r",?\s*metadata=\{[^{}]*\}")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# Tables XLA prints after the module header for source locations.
+_FRAME_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                 "StackFrames")
+
+
+def strip_metadata(text: str) -> str:
+    """Compiled HLO text without what only describes where an op came
+    from: every ``metadata={...}`` attribute (op names from
+    ``jax.named_scope`` and the tracing path, source lines, stack-frame
+    ids) and the stack-frame tables.  Two programs that differ only in
+    names and source locations strip to the same text."""
+    out, skipping = [], False
+    for line in text.splitlines():
+        if line.strip() in _FRAME_TABLES:
+            skipping = True
+        elif skipping:
+            skipping = bool(line.strip())      # a table ends at a blank
+        elif line.strip() or (out and out[-1]):
+            out.append(_METADATA.sub("", line))
+    return "\n".join(out)
+
+
+_TRANSFORM = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
+
+
+def scope_path(op_name: str) -> str:
+    """An ``op_name`` with JAX's transformation wrappers taken off each
+    component: ``jit(f)/transpose(jvp(aggregate))/bucket[1]/add`` ->
+    ``jit(f)/aggregate/bucket[1]/add``.  A scope entered inside a
+    transformed function (a custom_vjp backward, say) keeps its name
+    this way."""
+    parts = []
+    for part in op_name.split("/"):
+        m = _TRANSFORM.match(part)
+        while m:
+            part = m.group(1)
+            m = _TRANSFORM.match(part)
+        parts.append(part)
+    return "/".join(parts)
+
+
+def scope_collective_bytes(text: str, scope: str,
+                           kind: str = "collective-permute") -> int:
+    """Result bytes of the ``kind`` collectives (synchronous or
+    ``-start``) whose :func:`scope_path` holds ``scope`` as whole path
+    components, e.g. ``scope="bucket[0]/stage[1]"``.  Counted once per
+    instruction, as :func:`analyze` counts collectives outside loops."""
+    comps = parse_module(text)
+    comps.pop("__entry__", None)
+    want = "/" + scope + "/"
+    total = 0
+    for comp in comps.values():
+        for ins in comp.instrs:
+            if ins.op not in (kind, kind + "-start"):
+                continue
+            m = _OP_NAME.search(ins.args)
+            if m and want in "/" + scope_path(m.group(1)) + "/":
+                total += ins.result_bytes
+    return total

@@ -252,6 +252,7 @@ def sdpa(q, k, v, q_pos, k_pos, spec: ModelSpec, window: int = 0):
 # GQA layer (covers MHA / MQA by kv-head count); optional sliding window
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def gqa_forward(params, x, positions, spec: ModelSpec,
                 rope: bool = True):
     """Full-sequence GQA. x (B,S,d). Returns (out, kv) with kv for cache
@@ -265,8 +266,9 @@ def gqa_forward(params, x, positions, spec: ModelSpec,
     if rope:
         q = apply_rope(q, positions, spec.rope_theta)
         k = apply_rope(k, positions, spec.rope_theta)
-    out = sdpa(q, k, v, positions[0], positions[0], spec,
-               window=spec.sliding_window)
+    with jax.named_scope("sdpa"):
+        out = sdpa(q, k, v, positions[0], positions[0], spec,
+                   window=spec.sliding_window)
     out = out.reshape(b, s, h * hd) @ params["wo"].astype(cd)
     return out, (k, v)
 
@@ -315,6 +317,7 @@ def gqa_decode(params, x, cache_k, cache_v, pos, spec: ModelSpec,
 # MLA — multi-head latent attention (DeepSeek-V2); latent KV cache
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def mla_forward(params, x, positions, spec: ModelSpec):
     """Full-sequence MLA (non-absorbed expansion). Returns (out, latents)
     with latents = (c_kv, k_rope) for cache seeding."""
@@ -335,11 +338,13 @@ def mla_forward(params, x, positions, spec: ModelSpec):
     v = (c_kv @ params["wuv"].astype(cd)).reshape(b, s, h, vd)
 
     scale = 1.0 / np.sqrt(nd + rd)
-    sc = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
-          + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope)).astype(jnp.float32)
-    sc = sc * scale + _mask_bias(positions[0], positions[0], 0)
-    probs = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    with jax.named_scope("sdpa"):
+        sc = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+              + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope)
+              ).astype(jnp.float32)
+        sc = sc * scale + _mask_bias(positions[0], positions[0], 0)
+        probs = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
+        out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
     out = out.reshape(b, s, h * vd) @ params["wo"].astype(cd)
     return out, (c_kv, k_rope)
 

@@ -173,6 +173,7 @@ def layernorm(x, scale, bias=None, eps: float = 1e-5):
     return x.astype(dt)
 
 
+@jax.named_scope("norm")
 def norm(x, params, kind: str):
     if kind == "rmsnorm":
         return rmsnorm(x, params["scale"])

@@ -18,6 +18,7 @@ def mlp_params(key, d_model: int, d_ff: int, mlp_type: str):
     return p
 
 
+@jax.named_scope("mlp")
 def mlp_forward(params, x, mlp_type: str):
     cd = x.dtype
     h = x @ params["w1"].astype(cd)

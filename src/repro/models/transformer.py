@@ -122,6 +122,7 @@ def _block_decode(lp, h, cache_layer, pos, spec: ModelSpec, is_moe: bool):
 # embedding / head
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def embed_tokens(params, tokens, spec: ModelSpec, patches=None):
     cd = spec.compute_dtype
     h = params["embed"].astype(cd)[tokens]
@@ -133,6 +134,7 @@ def embed_tokens(params, tokens, spec: ModelSpec, patches=None):
     return h
 
 
+@jax.named_scope("head")
 def lm_logits(params, h, spec: ModelSpec):
     cd = spec.compute_dtype
     if spec.tie_embeddings or "lm_head" not in params:
@@ -199,7 +201,8 @@ def loss_fn(params, batch, spec: ModelSpec):
     logits, _, aux = forward(params, batch["tokens"], spec, patches=patches)
     if patches is not None:
         logits = logits[:, patches.shape[1]:]       # only text positions
-    loss = cross_entropy(logits, batch["labels"], batch.get("mask"))
+    with jax.named_scope("head"):
+        loss = cross_entropy(logits, batch["labels"], batch.get("mask"))
     total = loss + spec.router_aux_weight * aux["aux"]
     return total, {"ce": loss, "aux": aux["aux"], "drop": aux["drop"]}
 

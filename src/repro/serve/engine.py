@@ -70,15 +70,8 @@ class ServeEngine:
                 self.model, self.mesh, self.dp_axes, batch, cfg.max_seq)
             self._prefill_key = pkey
         with tracer.span("serve.prefill", cat="wall", batch=int(b),
-                         prompt_len=int(tokens.shape[1])) as sp:
+                         prompt_len=int(tokens.shape[1])):
             logits, cache = self._prefill(self.params, batch)
-            if tracer.enabled:
-                jax.block_until_ready((logits, cache))
-        if tracer.enabled:
-            telemetry.METRICS.histogram(
-                "serve_prefill_s",
-                help="host-timed prefill latency (s)"
-            ).observe(sp.t1 - sp.t0)
 
         key = (b, cfg.max_seq)
         if self._decode_key != key:
@@ -109,18 +102,11 @@ class ServeEngine:
                     out.extend(pad for _ in
                                range(cfg.max_new_tokens - len(out)))
                     break
-            with tracer.span("serve.decode", cat="wall", token=t) as sp:
+            with tracer.span("serve.decode", cat="wall", token=t):
                 logits, cache = self._decode(self.params, cache,
                                              cur[:, None])
                 rng, sub = jax.random.split(rng)
                 cur = self._sample(logits, sub)
-                if tracer.enabled:
-                    jax.block_until_ready(cur)
-            if tracer.enabled:
-                telemetry.METRICS.histogram(
-                    "serve_decode_s",
-                    help="host-timed per-token decode latency (s)"
-                ).observe(sp.t1 - sp.t0)
         return np.stack(out, axis=1)
 
     def _sample(self, logits, rng):

@@ -1,30 +1,30 @@
-"""Runtime telemetry: span tracing, metrics, and timeline closure.
+"""Runtime telemetry: host spans, metrics, and timeline closure.
 
 Three pieces (DESIGN.md §3.11):
 
 * :mod:`repro.telemetry.trace` — a :class:`Tracer` producing nested
-  ``Span(name, t0, t1, attrs)`` records keyed by the same IR paths the
-  analysis layer uses (``bucket[i].stage[j]``), exported as
-  Chrome-trace / Perfetto ``trace_event`` JSON plus a schema-versioned
-  ``repro/trace/v1`` record.
+  host-timed ``Span(name, t0, t1, attrs)`` records, each also entered
+  as a ``jax.profiler.TraceAnnotation`` so it lands on the profiler's
+  host plane, exported as Chrome-trace / Perfetto ``trace_event`` JSON
+  plus a schema-versioned ``repro/trace/v1`` record.
 * :mod:`repro.telemetry.metrics` — a process-local registry of
   counters / gauges / histograms (wire bytes by algorithm×codec,
-  PlanCache hits/misses/interning, step-time percentiles) with a JSON
-  snapshot and a text summary.
+  PlanCache hits/misses/interning) with a JSON snapshot and a text
+  summary.
 * :mod:`repro.telemetry.closure` — the measured-vs-predicted timeline
   closure: replays each distinct IR stage as its own jitted collective
   with host timers, fits a single calibration scalar, and gates the
   per-stage residuals in a declared band (``BENCH_telemetry.json``).
 
-Telemetry is **zero-cost when disabled** (the default): every hook in
-the execution path guards on :func:`enabled` and records host-side
-metadata only — no operation is ever inserted into a traced
-computation, so compiled HLO, schedule fingerprints, and all existing
-artifacts are byte-identical with telemetry on or off.
+Telemetry is off by default; every hook guards on :func:`enabled` and
+records host-side data only, so compiled programs are the same with it
+on or off.  Inside the compiled train step the layers are named by
+``jax.named_scope`` instead (always on, HLO metadata only), and the
+device trace of a profiler run times them.
 
-``closure`` imports jax and :mod:`repro.core`; it is deliberately NOT
-imported here so that low-level core modules (reducers, aggregator)
-can import :mod:`repro.telemetry` without a cycle.
+``closure`` imports :mod:`repro.core`; it is deliberately NOT imported
+here so that low-level core modules (the aggregator) can import
+:mod:`repro.telemetry` without a cycle.
 """
 from . import metrics, trace
 from .metrics import REGISTRY as METRICS

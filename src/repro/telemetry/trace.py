@@ -1,21 +1,19 @@
-"""Span tracing: nested host-timed spans keyed by ReduceSchedule IR paths.
+"""Span tracing: nested host-timed spans around executed work.
 
-A :class:`Span` is ``(name, cat, t0, t1, attrs, children)``.  Two
-categories exist and they mean different things (DESIGN.md §3.11):
+A :class:`Span` is ``(name, cat, t0, t1, attrs, children)``, recorded
+by a :class:`Tracer` on the host clock.  The only category is
+``cat="wall"``: host wall-clock around executed work (the launchers'
+dry-run replay, the serve engine's prefill and decode calls, the
+closure's ``probe:`` replays).
 
-* ``cat="wall"`` — real host wall-clock around an executed, synced
-  computation (``block_until_ready`` before the span closes).  These
-  are the only spans whose durations are measurements.
-* ``cat="trace"`` — spans recorded while jax TRACES a computation
-  (inside ``execute_stages`` / the aggregator).  Their durations are
-  tracing time, not device time; their value is the *structure* and
-  the *attributes* (IR path, algorithm, codec, wire bytes), which are
-  exact because they come from the same Stage objects the HLO
-  wire-check charges.
-
-Spans never touch the traced values, so enabling or disabling tracing
-cannot change a jaxpr, the compiled HLO, or a schedule fingerprint —
-that identity is pinned by tests/test_telemetry.py.
+Every span, while open, also holds a ``jax.profiler.TraceAnnotation``
+of its name, so under ``jax.profiler.start_trace`` it appears on the
+profiler's host plane on the device trace's clock, and an idle gap on
+the device can be attributed to it.  What runs INSIDE a compiled step
+is not a span: the program carries ``jax.named_scope`` names (the
+model's layers, ``aggregate/bucket[i]/stage[j]/hop[k]``, ``clip``,
+``optimizer``) in its HLO metadata, and the device trace times them
+(DESIGN.md §3.11).
 
 The exporter writes a single JSON file that is both Perfetto/
 ``chrome://tracing`` loadable (top-level ``traceEvents`` in the
@@ -28,7 +26,9 @@ import dataclasses
 import json
 import os
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
+
+import jax
 
 TRACE_SCHEMA = "repro/trace/v1"
 
@@ -36,7 +36,7 @@ TRACE_SCHEMA = "repro/trace/v1"
 # import time (the CLI drivers additionally accept explicit flags).
 ENV_VAR = "REPRO_TRACE"
 
-CATEGORIES = ("wall", "trace")
+CATEGORIES = ("wall",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,25 +112,28 @@ _NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self.span = span
+        self._annotation = jax.profiler.TraceAnnotation(span.name)
 
     def __enter__(self) -> Span:
+        self._annotation.__enter__()
         self._tracer._push(self.span)
         return self.span
 
     def __exit__(self, *exc) -> None:
         self._tracer._pop(self.span)
+        self._annotation.__exit__(*exc)
 
 
 class Tracer:
     """Collects a forest of nested spans.
 
-    Not thread-safe by design: every instrumented path (trace-time
-    hooks, driver wall timers, the replay probe) runs on one thread.
+    Not thread-safe by design: every instrumented path (launcher wall
+    timers, the serve engine, the replay probe) runs on one thread.
     """
 
     def __init__(self, config: Optional[TelemetryConfig] = None):
@@ -173,20 +176,6 @@ class Tracer:
         if self._stack and self._stack[-1] is span:
             self._stack.pop()
 
-    def current_path(self) -> str:
-        """IR path of the innermost open span that carries one.
-
-        Lets ``execute_stages`` build ``bucket[i].stage[j]`` paths
-        without threading the bucket index through its signature: the
-        aggregator opens the ``bucket[i]`` span, the executor asks for
-        the enclosing path.
-        """
-        for span in reversed(self._stack):
-            path = span.attrs.get("ir_path")
-            if path:
-                return str(path)
-        return ""
-
     def clear(self) -> None:
         self.roots = []
         self._stack = []
@@ -227,7 +216,7 @@ class Tracer:
                 "ts": (s.t0 - t_base) * 1e6,
                 "dur": max(s.duration_s, 0.0) * 1e6,
                 "pid": 0,
-                "tid": 0 if s.cat == "wall" else 1,
+                "tid": 0,
                 "args": {k: v for k, v in s.attrs.items()},
             })
         return {
@@ -248,43 +237,6 @@ def from_json(rec: dict) -> List[Span]:
         raise ValueError(f"not a {TRACE_SCHEMA} record: "
                          f"schema={rec.get('schema')!r}")
     return [Span.from_json(s) for s in rec.get("spans", [])]
-
-
-class TimedFn:
-    """Wrap a (jitted) callable with a wall span + latency histogram.
-
-    Proxies attribute access to the wrapped function so ``.lower`` /
-    AOT APIs keep working.  Only constructed when telemetry is enabled,
-    so the disabled path never pays the indirection.
-    """
-
-    def __init__(self, fn: Callable, name: str, histogram: str = ""):
-        self._fn = fn
-        self._name = name
-        self._histogram = histogram or f"{name}_s"
-
-    def __call__(self, *args, **kwargs):
-        import jax
-
-        from . import metrics
-
-        tracer = get_tracer()
-        with tracer.span(self._name, cat="wall") as sp:
-            out = self._fn(*args, **kwargs)
-            out = jax.block_until_ready(out)
-            sp.set("synced", True)
-        if isinstance(sp, Span):   # tracer may have been reconfigured off
-            metrics.REGISTRY.histogram(
-                self._histogram, help="host-timed latency (s)"
-            ).observe(sp.t1 - sp.t0)
-        return out
-
-    def __getattr__(self, item):
-        return getattr(self._fn, item)
-
-
-def timed_call(fn: Callable, name: str, histogram: str = "") -> Callable:
-    return TimedFn(fn, name, histogram)
 
 
 # -- module-global tracer ----------------------------------------------

@@ -17,6 +17,15 @@ Structure (DESIGN.md §3.1, §3.6):
                 └─ optimizer.update + apply      # replicated over data,
                                                  #   model-sharded via auto
 
+Named scopes (``jax.named_scope``, HLO metadata only): the aggregator's
+ops sit under ``aggregate``, clipping under ``clip``, the optimizer and
+the parameter add under ``optimizer``, and the model's layers under
+their own names (``embed``, ``attention``/``sdpa``, ``mlp``, ``norm``,
+``head``); JAX marks the forward ``jvp(``, the backward
+``transpose(jvp(`` and remat's recompute ``rematted_computation``.  A
+profiler's device trace times each op, and its ``op_name`` says which
+of these it belongs to.
+
 The data axes are MANUAL: the gradient sum over data shards happens only
 through the aggregator's explicit algorithm (the compiled HLO contains
 our collective-permutes, no XLA-chosen allreduce).  The ``model`` axis
@@ -50,7 +59,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import telemetry
 from repro.core import AggregatorConfig, GradientAggregator
 from repro.core import manual as manual_mod
 from repro.core.compat import shard_map
@@ -84,6 +92,15 @@ def make_train_step(model: ModelApi, optimizer: Optimizer,
     this path since their residual-stream sharding constraint is a
     GSPMD annotation the manual region cannot express.
     """
+    # The step's named scopes are in its op names, which a profile
+    # reads.  JAX's persistent cache leaves metadata out of its key by
+    # default, so a step whose scopes changed would load an executable
+    # compiled from an earlier version and show that version's names.
+    # Key on the metadata, and keep the callers' tracebacks out of it,
+    # so that one program compiled from two call sites is one entry.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     dp_axes = tuple(cfg.dp_axes)
     model_axis = "model" if "model" in mesh.axis_names else None
     seq_parallel = bool(getattr(model.spec, "seq_parallel", False))
@@ -127,13 +144,15 @@ def make_train_step(model: ModelApi, optimizer: Optimizer,
         # model-sharded leaves hold 1/m each, so their squared sums are
         # psum'd over the model axis (replicated leaves counted once);
         # on the legacy path GSPMD combines the auto-axis partial sums.
-        grads, gnorm = clip_by_global_norm(
-            grads, cfg.clip_norm,
-            sharded=sharded_mask if manual else None,
-            model_axis=model_axis if manual else None)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree_util.tree_map(
-            lambda p, u: p + u.astype(p.dtype), params, updates)
+        with jax.named_scope("clip"):
+            grads, gnorm = clip_by_global_norm(
+                grads, cfg.clip_norm,
+                sharded=sharded_mask if manual else None,
+                model_axis=model_axis if manual else None)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = jax.tree_util.tree_map(
+                lambda p, u: p + u.astype(p.dtype), params, updates)
         metrics = {**metrics, "loss": loss, "grad_norm": gnorm}
         metrics = {k: agg.mean_scalar(v) for k, v in metrics.items()}
         return params, opt_state, metrics
@@ -176,14 +195,6 @@ def make_train_step(model: ModelApi, optimizer: Optimizer,
         in_shardings=(ns(pspecs), ns(sspecs), batch_sh),
         out_shardings=(ns(pspecs), ns(sspecs), None),
         donate_argnums=(0, 1) if donate else ())
-    if telemetry.enabled():
-        # Host-timed wall span + step-time histogram around every
-        # executed step (the wrapper syncs with block_until_ready, so
-        # the span closes when the devices are done — DESIGN.md §3.11
-        # clock caveats).  Built ONLY when telemetry is on: the
-        # disabled path returns the raw jitted callable untouched.
-        jitted = telemetry.trace.timed_call(jitted, "train.step",
-                                            histogram="train_step_s")
     # "aggregator" rides along so callers (launch/dryrun, examples) can
     # report the resolved per-bucket schedule of strategy="auto".
     return jitted, {"params": pspecs, "opt": sspecs, "batch": bspecs,

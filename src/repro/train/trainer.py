@@ -43,23 +43,37 @@ class Trainer:
         return params, opt_state
 
     def run(self, params=None, opt_state=None, start_step: int = 0):
+        """Train from ``start_step`` to ``cfg.steps``; returns (params,
+        opt_state, history).  Each step runs inside a
+        ``jax.profiler.StepTraceAnnotation("train", step_num=step)``.
+
+        The rate clock starts once this call's first step has completed,
+        so its compilation is not counted: ``tokens_per_s`` in a log
+        entry covers the steps after the first, and the entry has none
+        until such a step has run.  Steps are dispatched without waiting;
+        a log step's ``float()`` of its metrics is the only other sync."""
         if params is None:
             params, opt_state = self.init_state()
         history = []
-        t0 = time.perf_counter()
+        t0 = None
         tokens_seen = 0
         for step in range(start_step, self.cfg.steps):
             batch = self.data_iter_fn(step)
-            params, opt_state, metrics = self.step_fn(params, opt_state,
-                                                      batch)
-            if "tokens" in batch:
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                params, opt_state, metrics = self.step_fn(params,
+                                                          opt_state, batch)
+            if t0 is None:
+                jax.block_until_ready(metrics)
+                t0 = time.perf_counter()
+            elif "tokens" in batch:
                 tokens_seen += int(np.prod(batch["tokens"].shape))
             if (step + 1) % self.cfg.log_every == 0 or \
                     step == self.cfg.steps - 1:
                 m = {k: float(v) for k, v in metrics.items()}
-                dt = time.perf_counter() - t0
                 m["step"] = step + 1
-                m["tokens_per_s"] = tokens_seen / max(dt, 1e-9)
+                if step > start_step:
+                    dt = time.perf_counter() - t0
+                    m["tokens_per_s"] = tokens_seen / max(dt, 1e-9)
                 history.append(m)
                 print(f"step {step + 1:5d} "
                       + " ".join(f"{k}={v:.4g}" for k, v in m.items()

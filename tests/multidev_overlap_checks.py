@@ -177,13 +177,17 @@ def check_overlap_train_step():
     print("overlap train step ok")
 
 
-def check_overlap_trace_spans():
-    """Telemetry closure on a REAL executed p=8 overlapped auto step
-    (DESIGN.md §3.11): every IR bucket/stage path resolves to a trace
-    span whose attributed wire bytes are the schedule's, the permute-
-    kind span bytes sum EXACTLY to the HLO-charged collective-permute
-    bytes, the measured replay probe lands inside the residual band,
+def check_overlap_ir_scopes():
+    """The IR path in the compiled program, on a REAL executed p=8
+    overlapped auto step (DESIGN.md §3.11): every IR bucket/stage path
+    is a scope of the HLO's op names, inside the backward (the
+    reductions run in the custom_vjp boundaries), each permute-kind
+    stage's collective-permute bytes under its scope are its
+    ``wire_bytes`` and together they are every permute the program
+    holds; the measured replay probe lands inside the residual band,
     and the exported trace is Perfetto-loadable."""
+    import re
+
     from repro import telemetry
     from repro.launch import hlo_analysis as H
     from repro.telemetry import closure, trace as trace_mod
@@ -200,25 +204,35 @@ def check_overlap_trace_spans():
         g = compiled(params, x)            # really executed, synced
         jax.block_until_ready(g)
         sched = agg.last_schedule
+        text = compiled.as_text()
 
-        spans = {s.attrs.get("ir_path"): s for s in tracer.iter_spans()
-                 if s.cat == "trace" and s.attrs.get("ir_path")}
+        op_names = re.findall(r'op_name="([^"]*)"', text)
+
+        def scoped(scope):
+            """The op names under ``aggregate/<scope>``, as written."""
+            want = "/aggregate/" + scope + "/"
+            return [n for n in op_names
+                    if want in "/" + H.scope_path(n) + "/"]
+
+        for bucket in sched.buckets:
+            found = scoped(bucket.path)
+            assert found, f"no scope for IR bucket {bucket.path}"
+            assert all("transpose(" in n for n in found), \
+                f"IR bucket {bucket.path} outside the backward"
         perm_sum = 0
         for path, _bucket, st in sched.iter_stages():
-            sp = spans.get(path)
-            assert sp is not None, f"no trace span for IR stage {path}"
-            assert sp.attrs["wire_bytes"] == st.wire_bytes, path
-            assert sp.attrs["algorithm"] == st.algorithm, path
-            if sp.attrs["hlo_kind"] == "collective-permute":
-                perm_sum += sp.attrs["wire_bytes"]
-        for bucket in sched.buckets:
-            assert bucket.path in spans, \
-                f"no trace span for IR bucket {bucket.path}"
-        charged = H.analyze(compiled.as_text()).collective_bytes.get(
+            scope = path.replace(".", "/")
+            assert scoped(scope), f"no scope for IR stage {path}"
+            got = H.scope_collective_bytes(text, "aggregate/" + scope)
+            if st.hlo_kind == "collective-permute":
+                assert got == st.wire_bytes, \
+                    f"{path}: {got} permute bytes under its scope, " \
+                    f"{st.wire_bytes} scheduled"
+            perm_sum += got
+        charged = H.analyze(text).collective_bytes.get(
             "collective-permute", 0)
         assert perm_sum == charged, \
-            f"span-attributed permute bytes {perm_sum} != " \
-            f"HLO-charged {charged}"
+            f"scoped permute bytes {perm_sum} != HLO-charged {charged}"
 
         # measured replay of the executed schedule: residuals in band
         measured = closure.measure_schedule(sched, reps=2, tracer=tracer)
@@ -238,7 +252,7 @@ def check_overlap_trace_spans():
             assert trace_mod.from_json(doc["repro"])
     finally:
         telemetry.configure(trace_mod.TelemetryConfig(enabled=False))
-    print(f"overlap trace spans ok (permute bytes {perm_sum} == "
+    print(f"overlap IR scopes ok (permute bytes {perm_sum} == "
           f"{charged}; probe max_ratio {rep['max_ratio']:.2f})")
 
 
@@ -339,7 +353,7 @@ if __name__ == "__main__":
     check_overlap_bitexact()
     check_overlap_mixed_strategies()
     check_overlap_train_step()
-    check_overlap_trace_spans()
+    check_overlap_ir_scopes()
     check_global_grad_norm()
     check_train_step_norm_matches_single_process()
     print("ALL OVERLAP CHECKS PASSED")

@@ -1,15 +1,18 @@
-"""Telemetry subsystem (DESIGN.md §3.11): span tracing, the metrics
-registry, and the measured-vs-predicted closure.
+"""Telemetry subsystem (DESIGN.md §3.11): host spans, the metrics
+registry, the measured-vs-predicted closure, and the named scopes that
+carry the IR path into the compiled program.
 
-The two hard invariants pinned here:
+The hard invariants pinned here:
 
-* IR-path resolution — every ``bucket[i].stage[j]`` trace span carries
-  the SAME wire-byte attribution as the producing ReduceSchedule, and
-  their sum equals the HLO-charged permute bytes (subprocess test on
-  forced host devices);
+* IR paths in the program — every ``bucket[i]`` and
+  ``bucket[i].stage[j]`` path of the ReduceSchedule is a scope in the
+  compiled HLO's ``op_name``s, and each stage's collective-permute bytes
+  under its scope equal its ``wire_bytes`` (subprocess test on forced
+  host devices);
 * disabled-mode identity — with ``TelemetryConfig(enabled=False)`` the
-  lowered HLO and the schedule fingerprint are byte-identical to a
-  telemetry-on build: spans never touch traced values.
+  compiled HLO, metadata aside, and the schedule fingerprint are the
+  same as a telemetry-on build's;
+* every wall span is on the profiler's host plane.
 """
 import json
 import os
@@ -37,7 +40,7 @@ def _telemetry_off_after():
 
 def test_disabled_span_is_shared_null_object():
     tracer = trace.Tracer(trace.TelemetryConfig(enabled=False))
-    s1 = tracer.span("a", cat="trace", ir_path="bucket[0]")
+    s1 = tracer.span("a", cat="wall", ir_path="bucket[0]")
     s2 = tracer.span("b")
     assert s1 is s2 is trace._NULL_SPAN
     with s1 as sp:
@@ -48,22 +51,19 @@ def test_disabled_span_is_shared_null_object():
 def test_unknown_category_rejected_only_when_enabled():
     tracer = trace.Tracer(trace.TelemetryConfig(enabled=True))
     with pytest.raises(ValueError):
-        tracer.span("x", cat="gpu")
+        tracer.span("x", cat="trace")
     off = trace.Tracer(trace.TelemetryConfig(enabled=False))
-    assert off.span("x", cat="gpu") is trace._NULL_SPAN
+    assert off.span("x", cat="trace") is trace._NULL_SPAN
 
 
 def test_span_nesting_ordering_and_roundtrip():
     tracer = trace.Tracer(trace.TelemetryConfig(enabled=True))
     with tracer.span("step", cat="wall") as outer:
-        with tracer.span("bucket", cat="trace",
-                         ir_path="bucket[0]") as b:
-            assert tracer.current_path() == "bucket[0]"
-            with tracer.span("stage", cat="trace",
-                             ir_path="bucket[0].stage[0]",
+        with tracer.span("bucket", ir_path="bucket[0]"):
+            with tracer.span("stage", ir_path="bucket[0].stage[0]",
                              wire_bytes=128):
-                assert tracer.current_path() == "bucket[0].stage[0]"
-        with tracer.span("bucket", cat="trace", ir_path="bucket[1]"):
+                pass
+        with tracer.span("bucket", ir_path="bucket[1]"):
             pass
     assert len(tracer.roots) == 1
     assert [c.attrs["ir_path"] for c in outer.children] == \
@@ -90,7 +90,7 @@ def test_exception_unwind_closes_dangling_spans():
     tracer = trace.Tracer(trace.TelemetryConfig(enabled=True))
     with pytest.raises(RuntimeError):
         with tracer.span("outer"):
-            ctx = tracer.span("inner", cat="trace")
+            ctx = tracer.span("inner", cat="wall")
             ctx.__enter__()           # never exited explicitly
             raise RuntimeError("boom")
     outer = tracer.roots[0]
@@ -102,7 +102,7 @@ def test_exception_unwind_closes_dangling_spans():
 def test_chrome_trace_is_perfetto_shaped(tmp_path):
     tracer = trace.Tracer(trace.TelemetryConfig(enabled=True))
     with tracer.span("outer", cat="wall"):
-        with tracer.span("inner", cat="trace", ir_path="bucket[0]"):
+        with tracer.span("inner", cat="wall", ir_path="bucket[0]"):
             pass
     path = tmp_path / "trace.json"
     tracer.write(str(path))
@@ -113,23 +113,41 @@ def test_chrome_trace_is_perfetto_shaped(tmp_path):
         assert ev["ph"] == "X"
         assert ev["ts"] >= 0 and ev["dur"] >= 0
         assert ev["cat"] in trace.CATEGORIES
-    assert {ev["tid"] for ev in evs} == {0, 1}   # wall vs trace tracks
+    assert {ev["tid"] for ev in evs} == {0}      # one host track
     assert doc["repro"]["schema"] == trace.TRACE_SCHEMA
     assert trace.from_json(doc["repro"])         # reloads as spans
 
 
-def test_timed_call_records_histogram():
-    import jax.numpy as jnp
+def test_wall_span_is_on_the_profiler_host_plane(tmp_path):
+    """A wall span also enters a ``jax.profiler.TraceAnnotation``: under
+    the profiler it is an event of the host plane, on the device
+    trace's clock, nested as the spans are."""
+    import glob
 
-    telemetry.configure(trace.TelemetryConfig(enabled=True))
-    fn = trace.timed_call(lambda x: x * 2, "unit.op", histogram="unit_s")
-    out = fn(jnp.ones((4,)))
-    assert float(out.sum()) == 8.0
-    snap = telemetry.METRICS.snapshot()["metrics"]["unit_s"]["values"][""]
-    assert snap["count"] == 1 and snap["min"] >= 0.0
-    tracer = telemetry.get_tracer()
-    assert tracer.roots[0].name == "unit.op"
-    assert tracer.roots[0].attrs["synced"] is True
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    tracer = telemetry.configure(trace.TelemetryConfig(enabled=True))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.span("unit.outer", cat="wall"):
+            with tracer.span("unit.inner", cat="wall"):
+                jnp.ones((4,)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    (host,) = [p for p in ProfileData.from_file(path).planes
+               if p.name == "/host:CPU"]
+    events = {e.name: e for line in host.lines for e in line.events
+              if e.name.startswith("unit.")}
+    assert set(events) == {"unit.outer", "unit.inner"}
+    outer, inner = events["unit.outer"], events["unit.inner"]
+    assert outer.start_ns <= inner.start_ns <= inner.end_ns \
+        <= outer.end_ns
+    assert [s.name for s in tracer.iter_spans()] == \
+        ["unit.outer", "unit.inner"]
 
 
 # ---------------------------------------------------------------------------
@@ -345,7 +363,7 @@ _SNIPPET = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 os.environ.pop("REPRO_TRACE", None)
-import sys
+import re, sys
 sys.path.insert(0, %r)
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
@@ -387,35 +405,41 @@ hlo_off = fn_off.lower(params, x).compile().as_text()
 fp_off = agg_off.last_schedule.fingerprint()
 
 # -- pass 2: telemetry ON ---------------------------------------------------
-tracer = telemetry.configure(trace.TelemetryConfig(enabled=True))
+telemetry.configure(trace.TelemetryConfig(enabled=True))
 fn_on, agg_on = build()
 hlo_on = fn_on.lower(params, x).compile().as_text()
 sched = agg_on.last_schedule
 
-# disabled-mode identity: spans never touch traced values
-assert hlo_on == hlo_off, "telemetry changed the compiled HLO"
+# disabled-mode identity: the program is the same, metadata aside
+assert H.strip_metadata(hlo_on) == H.strip_metadata(hlo_off), \
+    "telemetry changed the compiled program"
 assert sched.fingerprint() == fp_off, "telemetry changed the fingerprint"
 
-# every IR bucket/stage path resolved to a trace span with exact attrs
-spans = {s.attrs.get("ir_path"): s for s in tracer.iter_spans()
-         if s.cat == "trace" and s.attrs.get("ir_path")}
+# every IR bucket/stage path is a scope of the compiled program, under
+# the aggregator's scope, and each stage's permutes carry its hop scopes
+names = ["/" + H.scope_path(n) + "/"
+         for n in re.findall(r'op_name="([^"]*)"', hlo_on)]
+
+def scoped(scope):
+    return [n for n in names if "/aggregate/" + scope + "/" in n]
+
+for bucket in sched.buckets:
+    assert scoped(bucket.path), bucket.path
 stage_sum = 0
 for path, bucket, st in sched.iter_stages():
-    sp = spans[path]                      # KeyError = missing span
-    assert sp.attrs["wire_bytes"] == st.wire_bytes, path
-    assert sp.attrs["algorithm"] == st.algorithm, path
-    stage_sum += sp.attrs["wire_bytes"]
-for bucket in sched.buckets:
-    assert bucket.path in spans, bucket.path
+    scope = path.replace(".", "/")          # bucket[i]/stage[j]
+    assert scoped(scope), path
+    assert scoped(scope + "/hop[0]"), path
+    got = H.scope_collective_bytes(hlo_on, "aggregate/" + scope)
+    if st.hlo_kind == "collective-permute":
+        # the stage's own permutes, counted in the program, are its
+        # scheduled wire bytes
+        assert got == st.wire_bytes, (path, got, st.wire_bytes)
+    stage_sum += got
 
-# attributed wire bytes == HLO-charged permute bytes, exactly
+# and together they are every permute the program holds
 charged = H.analyze(hlo_on).collective_bytes.get("collective-permute", 0)
 assert stage_sum == charged, (stage_sum, charged)
-
-# per-hop children: each stage span carries its ppermute hop spans
-stage_spans = [spans[path] for path, _b, _s in sched.iter_stages()]
-assert all(any(c.name.startswith("hop[") for c in sp.children)
-           for sp in stage_spans)
 print("OK", stage_sum, "==", charged)
 """
 
