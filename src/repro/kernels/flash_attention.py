@@ -1,15 +1,36 @@
-"""Pallas TPU kernel: flash attention (forward) — the perf-critical
-compute layer for the 32k prefill shapes.
+"""Pallas TPU kernel: causal flash attention, forward and FlashAttention-2
+backward, tied together by a ``jax.custom_vjp``.
 
-TPU-native blocking: grid (batch·heads, n_q_blocks, n_kv_blocks) with the
-kv dimension iterated minor-most (sequential on TPU), carrying the
-online-softmax state (acc, m, l) in VMEM scratch across kv steps.
-Block shapes default to (128, head_dim) q-tiles × (128, head_dim)
-kv-tiles — MXU-aligned (128 lanes) and ~3·128·dh·4B of scratch.
+On a TPU backend ``models.attention.sdpa`` routes full-sequence causal
+self-attention here (training, remat's recompute and serve prefill);
+the CPU backend keeps the jnp paths in ``models.attention``, and tests
+run this kernel in interpret mode.
 
-The ops.py dispatcher uses the pure-JAX custom-VJP implementation
-(models.attention.sdpa_chunked) for CPU/dry-run paths; this kernel is the
-TPU target and is validated against ref.py in interpret mode.
+Layout: q (B, H, S, dh), k and v (B, KV, S, dh), H a multiple of KV;
+each grid step holds one (block_q, dh) tile of q against one
+(block_k, dh) tile of k and v, a query head reading its kv head
+``h // (H // KV)`` through the ``index_map`` (no repeated kv heads).
+
+Precision: the MXU takes its operands in the inputs' dtype (bf16 in the
+models) and accumulates in f32: q·kᵀ, p·v, dO·vᵀ, pᵀ·dO, dS·k, dSᵀ·q.
+Scores, the running max and sum, lse, delta, dP and dS before their
+cast, and every accumulator are f32.
+
+Causal block skipping: a kv block wholly above the diagonal (or wholly
+outside the sliding window) is neither computed (``pl.when``) nor
+fetched (the ``index_map`` clamps a skipped step to the block already
+resident, so no DMA is issued). A block that straddles the diagonal or
+the window's edge runs whole under the iota mask, or (the backward's
+passes) in tiles that skip what no query sees and mask only where they
+straddle.
+
+Block and tile sizes are a function of the sequence length
+(``block_sizes``), swept on a TPU v5e at head_dim 64.
+
+Grids: forward and dq pass (B, H, n_q, n_kv) with the kv axis innermost;
+dk/dv pass (B, KV, n_kv, rep·n_q), the inner axis running over the
+group's query heads and the q blocks, so dk and dv accumulate a kv
+head's whole group in VMEM.
 """
 from __future__ import annotations
 
@@ -24,248 +45,426 @@ from jax.experimental.pallas import tpu as pltpu
 from .backend import resolve_interpret
 
 NEG_INF = -1e30
+LANES = 128
+# (block_q, block_k, tile) of the forward, the dq pass and the dk/dv
+# pass, by sequence length, at head_dim 64 (swept on a TPU v5e;
+# PERF.md). Other lengths take the largest power-of-two multiple of 128
+# up to _DEFAULT that divides them (larger blocks won at 2048 and 4096),
+# the forward's straddling blocks whole and the backward's in tiles of
+# _BWD_TILE (each won its pass).
+_TABLE = {1024: ((512, 512, 512),) * 3}
+_DEFAULT = 1024
+_BWD_TILE = 512
+# Scoped VMEM the kernels may use: (block_q, block_k) f32 temporaries of
+# 1024 x 1024 are 4 MiB each.
+_VMEM_LIMIT = 64 * 2 ** 20
+
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))   # a · b
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      acc_ref, m_ref, l_ref, *, scale, block_q, block_k,
-                      causal, window, n_kv):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _fit(seq: int, cap: int):
+    """The largest power-of-two multiple of 128, at most ``cap``, that
+    divides ``seq``; None where 128 does not."""
+    b = cap
+    while b >= LANES:
+        if seq % b == 0:
+            return b
+        b //= 2
+    return None
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[...].astype(jnp.float32)          # (block_q, dh)
-    k = k_ref[...].astype(jnp.float32)          # (block_k, dh)
-    v = v_ref[...].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+def block_sizes(seq: int, head_dim: int):
+    """((bq, bk, tile) of the forward, of the dq pass, of the dk/dv pass)
+    for a sequence length and head size, or None where the kernel does
+    not take the shape (``seq`` not a multiple of 128, ``head_dim`` not a
+    multiple of 8)."""
+    if head_dim % 8:
+        return None
+    if seq in _TABLE:
+        return _TABLE[seq]
+    b = _fit(seq, _DEFAULT)
+    if b is None:
+        return None
+    return ((b, b, b),) + ((b, b, min(b, _BWD_TILE)),) * 2
 
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32,
-                                                    (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32,
-                                                    (block_q, block_k), 1)
-    mask = jnp.ones((block_q, block_k), jnp.bool_)
-    if causal:
-        mask &= q_pos >= k_pos
+
+# ---------------------------------------------------------------------------
+# which blocks run
+# ---------------------------------------------------------------------------
+
+def _kv_range(qi, bq, bk, causal, window, n_kv):
+    """First and last kv block that q block ``qi`` sees."""
+    if not causal:
+        return 0, n_kv - 1
+    last = (qi * bq + bq - 1) // bk
+    first = 0
     if window > 0:
-        mask &= (q_pos - k_pos) < window
-    s = jnp.where(mask, s, NEG_INF)
+        first = jnp.maximum(qi * bq - window + 1, 0) // bk
+    return first, last
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())))
-    m_ref[...] = m_new
 
-    @pl.when(ki == n_kv - 1)
+def _q_range(kj, bq, bk, causal, window, n_q):
+    """First and last q block that sees kv block ``kj``."""
+    if not causal:
+        return 0, n_q - 1
+    first = (kj * bk) // bq
+    last = n_q - 1
+    if window > 0:
+        last = jnp.minimum((kj * bk + bk - 2 + window) // bq, n_q - 1)
+    return first, last
+
+
+def _visible(q0, k0, tq, tk, causal, window):
+    """Whether some query of [q0, q0 + tq) sees some key of
+    [k0, k0 + tk)."""
+    if not causal:
+        return True
+    v = k0 <= q0 + tq - 1
+    if window > 0:
+        v = v & (q0 - (k0 + tk - 1) < window)
+    return v
+
+
+def _partial(q0, k0, tq, tk, causal, window):
+    """Whether some (query, key) pair of the tile is masked out."""
+    if not causal:
+        return False
+    m = k0 + tk - 1 > q0
+    if window > 0:
+        m = m | (q0 + tq - 1 - k0 >= window)
+    return m
+
+
+def _mask(q0, k0, shape, q_axis, causal, window):
+    """Boolean keep-mask of a tile whose first query is ``q0`` and first
+    key ``k0``; queries run along ``q_axis``."""
+    q = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    keep = q >= k if causal else jnp.ones(shape, jnp.bool_)
+    if window > 0:
+        keep &= (q - k) < window
+    return keep
+
+
+def _each_tile(q0, k0, bq, bk, tile, causal, window, update):
+    """``update(r, c, tq, tk, masked)`` over the (query rows r.., key rows
+    c..) tiles of a (bq, bk) block at (q0, k0) that some query sees: the
+    whole block where nothing in it is masked, else tiles of at most
+    ``tile`` a side, each masked only where it straddles the diagonal or
+    the window's edge, so the masked corner of a block is skipped."""
+    run = _visible(q0, k0, bq, bk, causal, window)
+    part = _partial(q0, k0, bq, bk, causal, window)
+    if part is False:
+        pl.when(run)(lambda: update(0, 0, bq, bk, False))
+        return
+    pl.when(run & jnp.logical_not(part))(
+        lambda: update(0, 0, bq, bk, False))
+    tq, tk = min(bq, tile), min(bk, tile)
+    for r in range(0, bq, tq):
+        for c in range(0, bk, tk):
+            vis = run & part & _visible(q0 + r, k0 + c, tq, tk, causal,
+                                        window)
+            edge = _partial(q0 + r, k0 + c, tq, tk, causal, window)
+            pl.when(vis & jnp.logical_not(edge))(
+                functools.partial(update, r, c, tq, tk, False))
+            pl.when(vis & edge)(functools.partial(update, r, c, tq, tk,
+                                                  True))
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
+                bq, bk, tile, causal, window, n_kv):
+    qi, kj = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(kj == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def update(r, c, tq, tk, masked):
+        rows, keys = pl.ds(r, tq), pl.ds(c, tk)
+        v = v_ref[keys, :]
+        s = jax.lax.dot_general(q_ref[rows, :], k_ref[keys, :], _NT,
+                                preferred_element_type=jnp.float32)
+        if masked:
+            s = jnp.where(_mask(qi * bq + r, kj * bk + c, s.shape, 0, causal,
+                                window), s, NEG_INF)
+        m_prev = m_sc[rows, :]                              # (tq, 128)
+        m_next = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next[:, :1])
+        l_sc[rows, :] = alpha * l_sc[rows, :] + p.sum(axis=1, keepdims=True)
+        acc_sc[rows, :] = acc_sc[rows, :] * alpha[:, :1] + \
+            jax.lax.dot_general(p.astype(v.dtype), v, _NN,
+                                preferred_element_type=jnp.float32)
+        m_sc[rows, :] = m_next
+
+    _each_tile(qi * bq, kj * bk, bq, bk, tile, causal, window, update)
+
+    @pl.when(kj == n_kv - 1)
     def _finish():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[...] = m_ref[...] + jnp.log(l_safe)
+        l = l_sc[...]
+        o_ref[...] = (acc_sc[...] / l[:, :1]).astype(o_ref.dtype)
+        lse = m_sc[...] + jnp.log(l)                        # (bq, 128)
+        lse_ref[...] = lse.T[:1, :]                         # (1, bq)
 
 
-def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
-                        block_q: int = 128, block_k: int = 128,
-                        interpret: bool | None = None,
-                        return_lse: bool = False):
-    """q,k,v: (B, S, H, dh) with kv already head-repeated (H heads).
-    Returns (B, S, H, dh) (+ lse (B,H,S) if return_lse) — pair with
-    flash_attention_bwd for the full training kernel.
-    ``interpret=None`` auto-detects the backend."""
-    interpret = resolve_interpret(interpret)
-    b, sq, h, dh = q.shape
-    sk = k.shape[1]
-    assert sq % block_q == 0 and sk % block_k == 0, (sq, sk)
-    scale = 1.0 / np.sqrt(dh)
-    # (B,S,H,dh) -> (B*H, S, dh)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, dh)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, dh)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dh)
-    n_q, n_kv = sq // block_q, sk // block_k
+def _fwd(q, k, v, causal, window, blocks, interpret):
+    """(out (B,H,S,dh), lse (B,H,1,S) f32) of pre-scaled q."""
+    b, h, s, dh = q.shape
+    kvh = k.shape[1]
+    rep = h // kvh
+    bq, bk, tile = blocks
+    n_q, n_kv = s // bq, s // bk
 
-    kernel = functools.partial(
-        _flash_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
-        causal=causal, window=window, n_kv=n_kv)
-    out, lse = pl.pallas_call(
+    def kv_map(bi, hi, qi, kj):
+        first, last = _kv_range(qi, bq, bk, causal, window, n_kv)
+        return bi, hi // rep, jnp.clip(kj, first, last), 0
+
+    kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, tile=tile,
+                               causal=causal, window=window, n_kv=n_kv)
+    return pl.pallas_call(
         kernel,
-        grid=(b * h, n_q, n_kv),
+        grid=(b, h, n_q, n_kv),
         in_specs=[
-            pl.BlockSpec((None, block_q, dh),
-                         lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((None, block_k, dh),
-                         lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, block_k, dh),
-                         lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((None, None, bq, dh),
+                         lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
+            pl.BlockSpec((None, None, bk, dh), kv_map),
+            pl.BlockSpec((None, None, bk, dh), kv_map),
         ],
-        out_specs=[pl.BlockSpec((None, block_q, dh),
-                                lambda bh, qi, ki: (bh, qi, 0)),
-                   pl.BlockSpec((None, block_q),
-                                lambda bh, qi, ki: (bh, qi))],
-        out_shape=[jax.ShapeDtypeStruct((b * h, sq, dh), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, sq), jnp.float32)],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dh), jnp.float32),   # acc
-            pltpu.VMEM((block_q,), jnp.float32),      # m
-            pltpu.VMEM((block_q,), jnp.float32),      # l
+        out_specs=[
+            pl.BlockSpec((None, None, bq, dh),
+                         lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
+            pl.BlockSpec((None, None, 1, bq),
+                         lambda bi, hi, qi, kj: (bi, hi, 0, qi)),
         ],
+        out_shape=[jax.ShapeDtypeStruct((b, h, s, dh), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),   # m
+                        pltpu.VMEM((bq, LANES), jnp.float32),   # l
+                        pltpu.VMEM((bq, dh), jnp.float32)],     # acc
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(qf, kf, vf)
-    out = out.reshape(b, h, sq, dh).transpose(0, 2, 1, 3)
-    if return_lse:
-        return out, lse.reshape(b, h, sq)
-    return out
+        name="flash_fwd",
+    )(q, k, v)
 
 
 # ---------------------------------------------------------------------------
-# backward (FlashAttention-2): two kernels — dq pass and dk/dv pass
+# backward: dq pass, then dk/dv pass
 # ---------------------------------------------------------------------------
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dq_acc, *, scale, block_q, block_k, causal,
-                     window, n_kv):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+               dq_sc, *, bq, bk, tile, causal, window, n_kv):
+    qi, kj = pl.program_id(2), pl.program_id(3)
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    q = q_ref[...].astype(jnp.float32)
-    k = k_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    do = do_ref[...].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = jnp.ones((block_q, block_k), jnp.bool_)
-    if causal:
-        mask &= q_pos >= k_pos
-    if window > 0:
-        mask &= (q_pos - k_pos) < window
-    s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse_ref[...][:, None])
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta_ref[...][:, None]) * scale
-    dq_acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())))
+    def update(r, c, tq, tk, masked):
+        rows, keys = pl.ds(r, tq), pl.ds(c, tk)
+        k = k_ref[keys, :]
+        s = jax.lax.dot_general(q_ref[rows, :], k, _NT,
+                                preferred_element_type=jnp.float32)
+        if masked:
+            s = jnp.where(_mask(qi * bq + r, kj * bk + c, s.shape, 0, causal,
+                                window), s, NEG_INF)
+        lse = jnp.expand_dims(lse_ref[0, rows], -1)          # (tq, 1)
+        delta = jnp.expand_dims(delta_ref[0, rows], -1)
+        p = jnp.exp(s - lse)
+        dp = jax.lax.dot_general(do_ref[rows, :], v_ref[keys, :], _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
+        dq_sc[rows, :] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(ki == n_kv - 1)
+    _each_tile(qi * bq, kj * bk, bq, bk, tile, causal, window, update)
+
+    @pl.when(kj == n_kv - 1)
     def _finish():
-        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_sc[...].astype(dq_ref.dtype)
 
 
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_acc, dv_acc, *, scale, block_q,
-                      block_k, causal, window, n_q):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                dv_ref, dk_sc, dv_sc, *, bq, bk, tile, causal, window, n_q,
+                rep):
+    kj, t = pl.program_id(2), pl.program_id(3)
+    qi = t % n_q
 
-    @pl.when(qi == 0)
+    @pl.when(t == 0)
     def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+        dk_sc[...] = jnp.zeros_like(dk_sc)
+        dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    q = q_ref[...].astype(jnp.float32)
-    k = k_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    do = do_ref[...].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = jnp.ones((block_q, block_k), jnp.bool_)
-    if causal:
-        mask &= q_pos >= k_pos
-    if window > 0:
-        mask &= (q_pos - k_pos) < window
-    s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse_ref[...][:, None])             # (bq, bk)
-    dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta_ref[...][:, None]) * scale
-    dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
+    def update(r, c, tq, tk, masked):
+        # transposed scores: keys along sublanes, queries along lanes,
+        # so lse and delta broadcast as rows
+        rows, keys = pl.ds(r, tq), pl.ds(c, tk)
+        q, do = q_ref[rows, :], do_ref[rows, :]
+        st = jax.lax.dot_general(k_ref[keys, :], q, _NT,
+                                 preferred_element_type=jnp.float32)
+        if masked:
+            st = jnp.where(_mask(qi * bq + r, kj * bk + c, st.shape, 1,
+                                 causal, window), st, NEG_INF)
+        pt = jnp.exp(st - lse_ref[:, rows])                 # (tk, tq)
+        dv_sc[keys, :] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v_ref[keys, :], do, _NT,
+                                  preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[:, rows])
+        dk_sc[keys, :] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
 
-    @pl.when(qi == n_q - 1)
+    _each_tile(qi * bq, kj * bk, bq, bk, tile, causal, window, update)
+
+    @pl.when(t == rep * n_q - 1)
     def _finish():
-        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[...] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def flash_attention_bwd(q, k, v, out, lse, dout, *, causal: bool = True,
-                        window: int = 0, block_q: int = 128,
-                        block_k: int = 128, interpret: bool | None = None):
-    """FlashAttention-2 backward. All (B,S,H,dh) except lse (B,H,S).
-    Returns (dq, dk, dv).  ``interpret=None`` auto-detects the
-    backend."""
-    interpret = resolve_interpret(interpret)
-    b, sq, h, dh = q.shape
-    sk = k.shape[1]
-    assert sq % block_q == 0 and sk % block_k == 0
+_BWD_PARAMS = dict(dimension_semantics=("parallel", "parallel", "parallel",
+                                        "arbitrary"),
+                   vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _dq(q, k, v, do, lse, delta, causal, window, blocks, interpret):
+    b, h, s, dh = q.shape
+    rep = h // k.shape[1]
+    bq, bk, tile = blocks
+    n_q, n_kv = s // bq, s // bk
+
+    def kv_map(bi, hi, qi, kj):
+        first, last = _kv_range(qi, bq, bk, causal, window, n_kv)
+        return bi, hi // rep, jnp.clip(kj, first, last), 0
+
+    def q_map(bi, hi, qi, kj):
+        return bi, hi, qi, 0
+
+    def row_map(bi, hi, qi, kj):
+        return bi, hi, 0, qi
+
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, bq=bq, bk=bk, tile=tile,
+                          causal=causal, window=window, n_kv=n_kv),
+        grid=(b, h, n_q, n_kv),
+        in_specs=[pl.BlockSpec((None, None, bq, dh), q_map),
+                  pl.BlockSpec((None, None, bk, dh), kv_map),
+                  pl.BlockSpec((None, None, bk, dh), kv_map),
+                  pl.BlockSpec((None, None, bq, dh), q_map),
+                  pl.BlockSpec((None, None, 1, bq), row_map),
+                  pl.BlockSpec((None, None, 1, bq), row_map)],
+        out_specs=pl.BlockSpec((None, None, bq, dh), q_map),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(**_BWD_PARAMS),
+        interpret=interpret,
+        name="flash_bwd_dq",
+    )(q, k, v, do, lse, delta)
+
+
+def _dkv(q, k, v, do, lse, delta, causal, window, blocks, interpret):
+    b, h, s, dh = q.shape
+    kvh = k.shape[1]
+    rep = h // kvh
+    bq, bk, tile = blocks
+    n_q, n_kv = s // bq, s // bk
+
+    def q_head(gi, t, kj):
+        first, last = _q_range(kj, bq, bk, causal, window, n_q)
+        return gi * rep + t // n_q, jnp.clip(t % n_q, first, last)
+
+    def q_map(bi, gi, kj, t):
+        hi, qi = q_head(gi, t, kj)
+        return bi, hi, qi, 0
+
+    def row_map(bi, gi, kj, t):
+        hi, qi = q_head(gi, t, kj)
+        return bi, hi, 0, qi
+
+    def kv_map(bi, gi, kj, t):
+        return bi, gi, kj, 0
+
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, bq=bq, bk=bk, tile=tile,
+                          causal=causal, window=window, n_q=n_q, rep=rep),
+        grid=(b, kvh, n_kv, rep * n_q),
+        in_specs=[pl.BlockSpec((None, None, bq, dh), q_map),
+                  pl.BlockSpec((None, None, bk, dh), kv_map),
+                  pl.BlockSpec((None, None, bk, dh), kv_map),
+                  pl.BlockSpec((None, None, bq, dh), q_map),
+                  pl.BlockSpec((None, None, 1, bq), row_map),
+                  pl.BlockSpec((None, None, 1, bq), row_map)],
+        out_specs=[pl.BlockSpec((None, None, bk, dh), kv_map),
+                   pl.BlockSpec((None, None, bk, dh), kv_map)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
+                        pltpu.VMEM((bk, dh), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(**_BWD_PARAMS),
+        interpret=interpret,
+        name="flash_bwd_dkv",
+    )(q, k, v, do, lse, delta)
+
+
+def _bwd(q, k, v, o, lse, do, causal, window, blocks, interpret):
+    """(dq, dk, dv) of pre-scaled q; every array in the kernel layout."""
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
+                    axis=-1)[:, :, None, :]                 # (B,H,1,S)
+    args = (q, k, v, do, lse, delta, causal, window)
+    dq = _dq(*args, blocks[0], interpret)
+    dk, dv = _dkv(*args, blocks[1], interpret)
+    return dq, dk, dv
+
+
+# ---------------------------------------------------------------------------
+# the differentiable kernel
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, window, blocks, interpret):
+    return _fwd(q, k, v, causal, window, blocks[0], interpret)[0]
+
+
+def _flash_fwd(q, k, v, causal, window, blocks, interpret):
+    o, lse = _fwd(q, k, v, causal, window, blocks[0], interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bwd(causal, window, blocks, interpret, res, do):
+    q, k, v, o, lse = res
+    return _bwd(q, k, v, o, lse, do, causal, window, blocks[1:], interpret)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
+                    blocks=None, interpret: bool | None = None):
+    """Softmax attention of q (B, S, H, dh) over k, v (B, S, KV, dh), H a
+    multiple of KV, positions 0..S-1 on both sides; differentiable.
+    ``blocks``, (bq, bk, tile) of each kernel, defaults to
+    :func:`block_sizes`; ``interpret=None``
+    interprets everywhere but on a TPU.  Returns (B, S, H, dh)."""
+    b, s, h, dh = q.shape
+    blocks = blocks or block_sizes(s, dh)
+    if blocks is None or h % k.shape[2] or any(
+            s % bq or s % bk or bq % min(bq, t) or bk % min(bk, t)
+            for bq, bk, t in blocks):
+        raise ValueError(f"flash_attention takes no shape {q.shape} "
+                         f"with kv {k.shape} and blocks {blocks}")
     scale = 1.0 / np.sqrt(dh)
-
-    def flat(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, -1, dh)
-
-    qf, kf, vf = flat(q), flat(k), flat(v)
-    dof, of = flat(dout), flat(out)
-    lsef = lse.reshape(b * h, sq)
-    delta = jnp.einsum("zsd,zsd->zs", dof.astype(jnp.float32),
-                       of.astype(jnp.float32))
-    n_q, n_kv = sq // block_q, sk // block_k
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, causal=causal, window=window,
-                          n_kv=n_kv),
-        grid=(b * h, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((None, block_q, dh), lambda z, i, j: (z, i, 0)),
-            pl.BlockSpec((None, block_k, dh), lambda z, i, j: (z, j, 0)),
-            pl.BlockSpec((None, block_k, dh), lambda z, i, j: (z, j, 0)),
-            pl.BlockSpec((None, block_q, dh), lambda z, i, j: (z, i, 0)),
-            pl.BlockSpec((None, block_q), lambda z, i, j: (z, i)),
-            pl.BlockSpec((None, block_q), lambda z, i, j: (z, i)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, dh),
-                               lambda z, i, j: (z, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, dh), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, causal=causal, window=window,
-                          n_q=n_q),
-        grid=(b * h, n_kv, n_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, dh), lambda z, j, i: (z, i, 0)),
-            pl.BlockSpec((None, block_k, dh), lambda z, j, i: (z, j, 0)),
-            pl.BlockSpec((None, block_k, dh), lambda z, j, i: (z, j, 0)),
-            pl.BlockSpec((None, block_q, dh), lambda z, j, i: (z, i, 0)),
-            pl.BlockSpec((None, block_q), lambda z, j, i: (z, i)),
-            pl.BlockSpec((None, block_q), lambda z, j, i: (z, i)),
-        ],
-        out_specs=[pl.BlockSpec((None, block_k, dh),
-                                lambda z, j, i: (z, j, 0)),
-                   pl.BlockSpec((None, block_k, dh),
-                                lambda z, j, i: (z, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b * h, sk, dh), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk, dh), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
-                        pltpu.VMEM((block_k, dh), jnp.float32)],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
-
-    def unflat(x):
-        return x.reshape(b, h, -1, dh).transpose(0, 2, 1, 3)
-
-    return unflat(dq), unflat(dk), unflat(dv)
+    qt = (q.astype(jnp.float32) * scale).astype(q.dtype).transpose(0, 2, 1, 3)
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
+    out = _flash(qt, kt, vt, causal, window, tuple(blocks),
+                 resolve_interpret(interpret))
+    return out.transpose(0, 2, 1, 3)

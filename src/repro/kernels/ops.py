@@ -10,11 +10,8 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
-from .backend import on_tpu
-from .flash_attention import flash_attention_fwd
 from .fused_adamw import adamw_update as _adamw_pallas
 from .fused_reduce import fused_reduce as _reduce_pallas
 
@@ -37,11 +34,3 @@ def adamw_update(p, g, m, v, lr, count, use_pallas: bool = False,
         return _adamw_pallas(p, g, m, v, **kw)
     return ref.adamw_update_ref(p, g, m, v, **kw)
 
-
-@functools.partial(jax.jit, static_argnames=("causal", "window",
-                                             "use_pallas"))
-def flash_attention(q, k, v, causal: bool = True, window: int = 0,
-                    use_pallas: bool = False):
-    if use_pallas:
-        return flash_attention_fwd(q, k, v, causal=causal, window=window)
-    return ref.flash_attention_ref(q, k, v, causal=causal, window=window)

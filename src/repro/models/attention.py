@@ -1,11 +1,12 @@
 """Attention layers: GQA/MQA/MHA, sliding-window, MLA, KV caches.
 
 Three execution modes share the same parameters:
-  * full-sequence (training / prefill) — plain masked attention for short
-    sequences, chunked online-softmax (flash-style, `lax.scan` over query
-    chunks) for long ones. The Pallas kernel in ``repro.kernels.flash_attention``
-    is the TPU-target twin of the chunked path and is validated against
-    the same oracle.
+  * full-sequence (training / prefill) — on a TPU, causal self-attention
+    runs the Pallas flash kernel (``repro.kernels.flash_attention``:
+    bf16 MXU operands, causal block skipping, its own backward). Elsewhere,
+    and for shapes the kernel does not take, plain masked attention for
+    short sequences and chunked online-softmax (flash-style, `lax.scan`
+    over query chunks) for long ones.
   * decode — one query token against a KV cache.
 
 Caches are dicts of stacked-over-layers arrays so the layer stack can
@@ -20,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import flash_attention
+from ..kernels.backend import on_tpu
 from . import common
 from .common import ModelSpec, apply_rope, dense_init
 
@@ -219,8 +222,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def sdpa_chunked(q, k, v, q_pos, k_pos, window: int, q_chunk: int):
     """Memory-efficient (flash) attention: custom-VJP online softmax,
-    O(chunk²) score memory in both passes. Pure-JAX twin of
-    kernels/flash_attention (same oracle)."""
+    O(chunk²) score memory in both passes; the path for long sequences
+    where the Pallas kernel is not taken."""
     b, sq, h, dh = q.shape
     kvh = k.shape[2]
     k = jnp.repeat(k, h // kvh, axis=2)   # grads sum back over rep groups
@@ -241,7 +244,26 @@ def sdpa_chunked(q, k, v, q_pos, k_pos, window: int, q_chunk: int):
     return out[:, :sq]
 
 
+def _unpartitioned() -> bool:
+    """Whether the computation being traced runs whole on each device,
+    which a Pallas kernel needs (the compiler cannot partition it):
+    inside a ``shard_map`` whose automatic axes all have size 1, or
+    outside any mesh on a one-device backend."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return jax.device_count() == 1
+    return all(n == 1 or a in mesh.manual_axes
+               for a, n in mesh.shape.items())
+
+
 def sdpa(q, k, v, q_pos, k_pos, spec: ModelSpec, window: int = 0):
+    """Causal attention core. On a TPU, self-attention over one shared
+    positions array (callers pass positions 0..S-1) runs the Pallas flash
+    kernel where its block sizes divide S; otherwise plain attention up
+    to ``spec.attn_full_seq_max`` positions, the chunked path beyond."""
+    if on_tpu() and q_pos is k_pos and _unpartitioned() and \
+            flash_attention.block_sizes(q.shape[1], q.shape[3]):
+        return flash_attention.flash_attention(q, k, v, window=window)
     if q.shape[1] <= spec.attn_full_seq_max and \
             k.shape[1] <= spec.attn_full_seq_max:
         return sdpa_full(q, k, v, q_pos, k_pos, window)
@@ -266,9 +288,9 @@ def gqa_forward(params, x, positions, spec: ModelSpec,
     if rope:
         q = apply_rope(q, positions, spec.rope_theta)
         k = apply_rope(k, positions, spec.rope_theta)
+    pos = positions[0]
     with jax.named_scope("sdpa"):
-        out = sdpa(q, k, v, positions[0], positions[0], spec,
-                   window=spec.sliding_window)
+        out = sdpa(q, k, v, pos, pos, spec, window=spec.sliding_window)
     out = out.reshape(b, s, h * hd) @ params["wo"].astype(cd)
     return out, (k, v)
 

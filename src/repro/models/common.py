@@ -32,8 +32,10 @@ class ModelSpec:
     attention_type: str = "gqa"    # gqa | mla
     sliding_window: int = 0        # >0 -> sliding-window attention
     attn_chunk: int = 1024         # q-chunk for online-softmax attention
-    attn_full_seq_max: int = 2048  # seqs <= this use plain attention;
-                                   # longer ones take the flash path
+    attn_full_seq_max: int = 2048  # off the Pallas kernel (CPU, or a
+                                   # length its blocks do not divide):
+                                   # seqs <= this use plain attention,
+                                   # longer ones the chunked jnp path
 
     # MLA (DeepSeek-V2)
     kv_lora_rank: int = 0

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 
 
@@ -92,8 +93,7 @@ def test_flash_attention(s, h, dh, causal, window):
                           jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(2), (2, s, h, dh),
                           jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              use_pallas=True)
+    got = fa.flash_attention(q, k, v, causal=causal, window=window)
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=1e-4)
@@ -106,7 +106,7 @@ def test_flash_attention_bf16():
                           jnp.bfloat16)
     v = jax.random.normal(jax.random.PRNGKey(2), (1, 256, 2, 64),
                           jnp.bfloat16)
-    got = ops.flash_attention(q, k, v, use_pallas=True)
+    got = fa.flash_attention(q, k, v)
     want = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -116,10 +116,8 @@ def test_flash_attention_bf16():
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 100),
                                            (False, 0)])
 def test_flash_attention_backward(causal, window):
-    """Pallas FA-2 backward kernels (dq pass + dk/dv pass) vs autodiff of
-    the naive oracle."""
-    from repro.kernels.flash_attention import (flash_attention_bwd,
-                                               flash_attention_fwd)
+    """The FA-2 backward kernels (dq pass, dk/dv pass) through the
+    kernel's custom VJP vs autodiff of the naive oracle."""
     key = jax.random.PRNGKey(0)
     B, S, H, DH = 1, 256, 2, 64
     q = jax.random.normal(key, (B, S, H, DH), jnp.float32)
@@ -129,10 +127,10 @@ def test_flash_attention_backward(causal, window):
                           jnp.float32)
     do = jax.random.normal(jax.random.PRNGKey(3), (B, S, H, DH),
                            jnp.float32)
-    out, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                   return_lse=True)
-    dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, do, causal=causal,
-                                     window=window)
+    blocks = ((128, 128, 128),) * 3
+    _, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, window=window, blocks=blocks), q, k, v)
+    dq, dk, dv = vjp(do)
 
     def f(q, k, v):
         return (ref.flash_attention_ref(q, k, v, causal=causal,

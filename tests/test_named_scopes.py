@@ -32,7 +32,10 @@ from repro.data.synthetic import SyntheticText
 from repro.models import build_model
 from repro.optim import adamw
 from repro.train import TrainStepConfig, make_train_step
+import repro.models.attention as A
 
+# the TPU route of the attention core, its kernels interpreted here
+A.on_tpu = lambda: {kernel}
 spec = dataclasses.replace(get_spec("smollm-360m").reduced(), num_layers=2,
                            remat=True)
 model = build_model(spec)
@@ -85,21 +88,22 @@ def _executed(text: str):
             if instrs[n]["opcode"] not in skip + tr.CONTAINERS]
 
 
-# Plain attention (16 positions), and the chunked flash path with its
-# custom backward (128 positions, past the reduced spec's
-# attn_full_seq_max of 64).
-@pytest.mark.timeout(300)
-@pytest.mark.parametrize("seq", [16, 128])
-def test_every_op_of_the_step_is_in_a_phase_or_scope(seq, tmp_path):
+def _step_text(seq, kernel, tmp_path) -> str:
     out = str(tmp_path / "step.hlo.txt")
-    code = _STEP.format(src=os.path.join(ROOT, "src"), seq=seq, out=out)
+    code = _STEP.format(src=os.path.join(ROOT, "src"), seq=seq, out=out,
+                        kernel=kernel)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     p = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=280)
     assert p.returncode == 0, p.stderr[-3000:]
     with open(out) as f:
-        text = f.read()
+        return f.read()
+
+
+def _check_phases_and_scopes(text):
+    """Every op in a phase or scope; the attention core in the forward,
+    the recompute and the backward.  Returns the executed ops."""
     ops = _executed(text)
     loose = []
     for name, opcode, path in ops:
@@ -117,7 +121,34 @@ def test_every_op_of_the_step_is_in_a_phase_or_scope(seq, tmp_path):
     phases = {scopes.phase(path) for _, _, path in ops}
     assert set(scopes.PHASES) - {"unscoped"} <= phases
     # the attention core runs in the forward, the recompute and the
-    # backward (for the flash path: its custom backward)
+    # backward (for the flash paths: their custom backward)
     core = {scopes.phase(path) for _, _, path in ops
             if scopes.ATTN_CORE in scopes.names(path)}
     assert {"forward", "recompute", "backward"} <= core
+    return ops
+
+
+# Plain attention (16 positions), and the chunked flash path with its
+# custom backward (128 positions, past the reduced spec's
+# attn_full_seq_max of 64).
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("seq", [16, 128])
+def test_every_op_of_the_step_is_in_a_phase_or_scope(seq, tmp_path):
+    _check_phases_and_scopes(_step_text(seq, False, tmp_path))
+
+
+@pytest.mark.timeout(300)
+def test_kernel_path_sits_under_sdpa_in_every_phase(tmp_path):
+    """The TPU route (128 positions, one block of the Pallas kernel; its
+    kernels interpreted here): the forward kernel runs under ``sdpa`` in
+    the forward and in the recompute, the dq and dk/dv kernels in the
+    backward, so ``attn_core_ms`` and the phases keep measuring the
+    same ops."""
+    ops = _check_phases_and_scopes(_step_text(128, True, tmp_path))
+    kernels = {(scopes.phase(path), k) for _, _, path in ops
+               if scopes.ATTN_CORE in scopes.names(path)
+               for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+               if k in scopes.names(path)}
+    assert kernels == {("forward", "flash_fwd"), ("recompute", "flash_fwd"),
+                       ("backward", "flash_bwd_dq"),
+                       ("backward", "flash_bwd_dkv")}

@@ -1,4 +1,5 @@
-"""Ahead-of-time v5e compiles of the aggregation kernels at real widths.
+"""Ahead-of-time v5e compiles of the aggregation kernels and the flash
+attention kernel at real widths.
 
 Off the chip the hop kernels run through the ``_HostRef`` direct
 lowering or the Pallas interpreter, neither of which applies Mosaic's
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import fused_hop as fh
 from repro.kernels.fused_reduce import fused_reduce
 
@@ -92,3 +94,24 @@ def test_fused_reduce_compiles(one_chip):
     x = _sds((4, N), jnp.float32, one_chip)
     txt = _compile_text(lambda v: fused_reduce(v, interpret=False), x)
     assert "tpu_custom_call" in txt
+
+
+# The attention cells' shapes (B, S, H, KV, dh): smollm-360m at 6 x 2048
+# and 4 x 1024, granite-3-2b at 1 x 4096.
+@pytest.mark.parametrize("b,s,h,kv,dh", [(6, 2048, 15, 5, 64),
+                                         (4, 1024, 15, 5, 64),
+                                         (1, 4096, 32, 8, 64)])
+def test_flash_attention_compiles(one_chip, b, s, h, kv, dh):
+    """Forward and both backward kernels at the blocks the shape
+    picks."""
+    q = _sds((b, s, h, dh), jnp.bfloat16, one_chip)
+    k = _sds((b, s, kv, dh), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, interpret=False), q, k, v)
+        return out, vjp(do)
+    txt = _compile_text(fwd_bwd, q, k, k, q)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in txt
+    assert txt.count("tpu_custom_call") == 3
