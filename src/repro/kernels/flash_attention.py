@@ -6,10 +6,13 @@ self-attention here (training, remat's recompute and serve prefill);
 the CPU backend keeps the jnp paths in ``models.attention``, and tests
 run this kernel in interpret mode.
 
-Layout: q (B, H, S, dh), k and v (B, KV, S, dh), H a multiple of KV;
+Layout: q and k (B, H|KV, S, dh), v (B, KV, S, dv), H a multiple of KV
+(v's head size may differ from q's and k's: MLA's 128 against 192);
 each grid step holds one (block_q, dh) tile of q against one
-(block_k, dh) tile of k and v, a query head reading its kv head
-``h // (H // KV)`` through the ``index_map`` (no repeated kv heads).
+(block_k, dh) tile of k and (block_k, dv) tile of v, a query head
+reading its kv head ``h // (H // KV)`` through the ``index_map`` (no
+repeated kv heads).  The scores' scale is set by the caller (default
+1/sqrt(dh)) and folded into q before the kernels.
 
 Precision: the MXU takes its operands in the inputs' dtype (bf16 in the
 models) and accumulates in f32: q·kᵀ, p·v, dO·vᵀ, pᵀ·dO, dS·k, dSᵀ·q.
@@ -24,8 +27,9 @@ the window's edge runs whole under the iota mask, or (the backward's
 passes) in tiles that skip what no query sees and mask only where they
 straddle.
 
-Block and tile sizes are a function of the sequence length
-(``block_sizes``), swept on a TPU v5e at head_dim 64.
+Block and tile sizes are a function of the sequence length and head
+size (``block_sizes``), swept on a TPU v5e at head_dim 64 and at MLA's
+192/128.
 
 Grids: forward and dq pass (B, H, n_q, n_kv) with the kv axis innermost;
 dk/dv pass (B, KV, n_kv, rep·n_q), the inner axis running over the
@@ -47,12 +51,15 @@ from .backend import resolve_interpret
 NEG_INF = -1e30
 LANES = 128
 # (block_q, block_k, tile) of the forward, the dq pass and the dk/dv
-# pass, by sequence length, at head_dim 64 (swept on a TPU v5e;
-# PERF.md). Other lengths take the largest power-of-two multiple of 128
-# up to _DEFAULT that divides them (larger blocks won at 2048 and 4096),
+# pass, by (sequence length, head_dim), else by sequence length (swept
+# on a TPU v5e, at head_dim 64 and at MLA's 192 with v 128; PERF.md).
+# Other shapes take the largest power-of-two multiple of 128 up to
+# _DEFAULT that divides the length (larger blocks won at 2048 and 4096),
 # the forward's straddling blocks whole and the backward's in tiles of
 # _BWD_TILE (each won its pass).
-_TABLE = {1024: ((512, 512, 512),) * 3}
+_TABLE = {1024: ((512, 512, 512),) * 3,
+          (8192, 192): ((2048, 1024, 1024), (2048, 1024, 512),
+                        (1024, 1024, 512))}
 _DEFAULT = 1024
 _BWD_TILE = 512
 # Scoped VMEM the kernels may use: (block_q, block_k) f32 temporaries of
@@ -81,8 +88,9 @@ def block_sizes(seq: int, head_dim: int):
     multiple of 8)."""
     if head_dim % 8:
         return None
-    if seq in _TABLE:
-        return _TABLE[seq]
+    for key in ((seq, head_dim), seq):
+        if key in _TABLE:
+            return _TABLE[key]
     b = _fit(seq, _DEFAULT)
     if b is None:
         return None
@@ -215,8 +223,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
 
 
 def _fwd(q, k, v, causal, window, blocks, interpret):
-    """(out (B,H,S,dh), lse (B,H,1,S) f32) of pre-scaled q."""
+    """(out (B,H,S,dv), lse (B,H,1,S) f32) of pre-scaled q."""
     b, h, s, dh = q.shape
+    dv = v.shape[-1]
     kvh = k.shape[1]
     rep = h // kvh
     bq, bk, tile = blocks
@@ -235,19 +244,19 @@ def _fwd(q, k, v, causal, window, blocks, interpret):
             pl.BlockSpec((None, None, bq, dh),
                          lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
             pl.BlockSpec((None, None, bk, dh), kv_map),
-            pl.BlockSpec((None, None, bk, dh), kv_map),
+            pl.BlockSpec((None, None, bk, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, bq, dh),
+            pl.BlockSpec((None, None, bq, dv),
                          lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
             pl.BlockSpec((None, None, 1, bq),
                          lambda bi, hi, qi, kj: (bi, hi, 0, qi)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((b, h, s, dh), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
                    jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),   # m
                         pltpu.VMEM((bq, LANES), jnp.float32),   # l
-                        pltpu.VMEM((bq, dh), jnp.float32)],     # acc
+                        pltpu.VMEM((bq, dv), jnp.float32)],     # acc
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
@@ -338,6 +347,7 @@ _BWD_PARAMS = dict(dimension_semantics=("parallel", "parallel", "parallel",
 
 def _dq(q, k, v, do, lse, delta, causal, window, blocks, interpret):
     b, h, s, dh = q.shape
+    dv = v.shape[-1]
     rep = h // k.shape[1]
     bq, bk, tile = blocks
     n_q, n_kv = s // bq, s // bk
@@ -358,8 +368,8 @@ def _dq(q, k, v, do, lse, delta, causal, window, blocks, interpret):
         grid=(b, h, n_q, n_kv),
         in_specs=[pl.BlockSpec((None, None, bq, dh), q_map),
                   pl.BlockSpec((None, None, bk, dh), kv_map),
-                  pl.BlockSpec((None, None, bk, dh), kv_map),
-                  pl.BlockSpec((None, None, bq, dh), q_map),
+                  pl.BlockSpec((None, None, bk, dv), kv_map),
+                  pl.BlockSpec((None, None, bq, dv), q_map),
                   pl.BlockSpec((None, None, 1, bq), row_map),
                   pl.BlockSpec((None, None, 1, bq), row_map)],
         out_specs=pl.BlockSpec((None, None, bq, dh), q_map),
@@ -373,6 +383,7 @@ def _dq(q, k, v, do, lse, delta, causal, window, blocks, interpret):
 
 def _dkv(q, k, v, do, lse, delta, causal, window, blocks, interpret):
     b, h, s, dh = q.shape
+    dv = v.shape[-1]
     kvh = k.shape[1]
     rep = h // kvh
     bq, bk, tile = blocks
@@ -399,16 +410,16 @@ def _dkv(q, k, v, do, lse, delta, causal, window, blocks, interpret):
         grid=(b, kvh, n_kv, rep * n_q),
         in_specs=[pl.BlockSpec((None, None, bq, dh), q_map),
                   pl.BlockSpec((None, None, bk, dh), kv_map),
-                  pl.BlockSpec((None, None, bk, dh), kv_map),
-                  pl.BlockSpec((None, None, bq, dh), q_map),
+                  pl.BlockSpec((None, None, bk, dv), kv_map),
+                  pl.BlockSpec((None, None, bq, dv), q_map),
                   pl.BlockSpec((None, None, 1, bq), row_map),
                   pl.BlockSpec((None, None, 1, bq), row_map)],
         out_specs=[pl.BlockSpec((None, None, bk, dh), kv_map),
-                   pl.BlockSpec((None, None, bk, dh), kv_map)],
+                   pl.BlockSpec((None, None, bk, dv), kv_map)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
-                        pltpu.VMEM((bk, dh), jnp.float32)],
+                        pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(**_BWD_PARAMS),
         interpret=interpret,
         name="flash_bwd_dkv",
@@ -448,20 +459,23 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    blocks=None, interpret: bool | None = None):
-    """Softmax attention of q (B, S, H, dh) over k, v (B, S, KV, dh), H a
-    multiple of KV, positions 0..S-1 on both sides; differentiable.
+                    scale: float | None = None, blocks=None,
+                    interpret: bool | None = None):
+    """Softmax attention of q (B, S, H, dh) over k (B, S, KV, dh) and
+    v (B, S, KV, dv), H a multiple of KV, positions 0..S-1 on both sides;
+    differentiable.  Scores are scaled by ``scale`` (default 1/sqrt(dh)).
     ``blocks``, (bq, bk, tile) of each kernel, defaults to
     :func:`block_sizes`; ``interpret=None``
-    interprets everywhere but on a TPU.  Returns (B, S, H, dh)."""
+    interprets everywhere but on a TPU.  Returns (B, S, H, dv)."""
     b, s, h, dh = q.shape
     blocks = blocks or block_sizes(s, dh)
-    if blocks is None or h % k.shape[2] or any(
+    if blocks is None or h % k.shape[2] or v.shape[-1] % 8 or any(
             s % bq or s % bk or bq % min(bq, t) or bk % min(bk, t)
             for bq, bk, t in blocks):
         raise ValueError(f"flash_attention takes no shape {q.shape} "
-                         f"with kv {k.shape} and blocks {blocks}")
-    scale = 1.0 / np.sqrt(dh)
+                         f"with kv {k.shape}, v {v.shape} and blocks "
+                         f"{blocks}")
+    scale = 1.0 / np.sqrt(dh) if scale is None else scale
     qt = (q.astype(jnp.float32) * scale).astype(q.dtype).transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)

@@ -198,14 +198,16 @@ def compute_roofline(cost: dict, coll: CollectiveStats, chips: int,
 # ---------------------------------------------------------------------------
 
 def active_params(spec) -> float:
-    """Active parameter count (MoE counts top_k + shared experts only)."""
+    """Active parameter count (MoE counts top_k + shared experts only;
+    of the experts a chip holds, their share of the top_k)."""
     total = 0.0
     if spec.num_experts:
-        # replace expert bank with active experts
+        # replace the held expert bank with its active share
         per_expert = 3 * spec.d_model * spec.moe_d_ff
         n_moe_layers = spec.num_layers - spec.first_dense_layers
-        total -= n_moe_layers * spec.num_experts * per_expert
-        total += n_moe_layers * (spec.top_k
+        held = spec.held_experts
+        total -= n_moe_layers * held * per_expert
+        total += n_moe_layers * (spec.top_k * held / spec.num_experts
                                  + spec.num_shared_experts) * per_expert
     return total
 

@@ -24,7 +24,7 @@ import numpy as np
 from ..kernels import flash_attention
 from ..kernels.backend import on_tpu
 from . import common
-from .common import ModelSpec, apply_rope, dense_init
+from .common import ModelSpec, apply_rope, dense_init, norm_params, rmsnorm
 
 NEG_INF = -1e30
 
@@ -53,6 +53,7 @@ def mla_params(key, spec: ModelSpec):
     return {
         "wq": dense_init(ks[0], (d, h * (nd + rd))),
         "wdkv": dense_init(ks[1], (d, r + rd)),       # latent + shared rope key
+        "kv_norm": norm_params(r, "rmsnorm"),         # on the latent
         "wuk": dense_init(ks[2], (r, h * nd)),
         "wuv": dense_init(ks[3], (r, h * vd)),
         "wo": dense_init(ks[4], (h * vd, d)),
@@ -71,15 +72,17 @@ def _mask_bias(q_pos, k_pos, window: int):
     return jnp.where(m, 0.0, NEG_INF)
 
 
-def sdpa_full(q, k, v, q_pos, k_pos, window: int = 0):
-    """Plain attention. q (B,Sq,H,dh); k,v (B,Sk,KV,dh). fp32 softmax."""
+def sdpa_full(q, k, v, q_pos, k_pos, window: int = 0, scale=None):
+    """Plain attention. q, k (B,S,H|KV,dh); v (B,Sk,KV,dv). fp32 softmax;
+    scores scaled by ``scale`` (default 1/sqrt(dh))."""
     b, sq, h, dh = q.shape
     kvh = k.shape[2]
     rep = h // kvh
     k = jnp.repeat(k, rep, axis=2)
     v = jnp.repeat(v, rep, axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    scores = scores / np.sqrt(dh) + _mask_bias(q_pos, k_pos, window)
+    scores = scores / np.sqrt(dh) if scale is None else scores * scale
+    scores = scores + _mask_bias(q_pos, k_pos, window)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
@@ -89,17 +92,18 @@ def _chunk(x, n, c, axis=1):
     return jnp.moveaxis(x.reshape(shape), axis, 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _flash(q, k, v, q_pos, k_pos, window: int, chunk: int):
-    out, _ = _flash_fwd_impl(q, k, v, q_pos, k_pos, window, chunk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash(q, k, v, q_pos, k_pos, window: int, chunk: int, scale: float):
+    out, _ = _flash_fwd_impl(q, k, v, q_pos, k_pos, window, chunk, scale)
     return out
 
 
-def _flash_fwd_impl(q, k, v, q_pos, k_pos, window, chunk):
+def _flash_fwd_impl(q, k, v, q_pos, k_pos, window, chunk, scale):
     """FlashAttention-2 forward: online softmax over kv chunks inside a
-    scan over q chunks. q,k,v (B,S,H,dh) (kv already head-repeated);
-    fp32 accumulation. Returns (out, lse)."""
+    scan over q chunks. q,k (B,S,H,dh), v (B,S,H,dv) (kv already
+    head-repeated); fp32 accumulation. Returns (out, lse)."""
     b, sq, h, dh = q.shape
+    dv = v.shape[-1]
     sk = k.shape[1]
     nq, nk = sq // chunk, sk // chunk
     qs = _chunk(q, nq, chunk)                        # (nq,B,C,H,dh)
@@ -107,7 +111,6 @@ def _flash_fwd_impl(q, k, v, q_pos, k_pos, window, chunk):
     ks = _chunk(k, nk, chunk)
     vs = _chunk(v, nk, chunk)
     kps = k_pos.reshape(nk, chunk)
-    scale = 1.0 / np.sqrt(dh)
 
     def q_step(_, qc):
         qb, qp = qc
@@ -125,7 +128,7 @@ def _flash_fwd_impl(q, k, v, q_pos, k_pos, window, chunk):
                 "bhqk,bkhd->bhqd", p, vb.astype(jnp.float32))
             return (acc, m_new, l), None
 
-        acc0 = jnp.zeros((b, h, chunk, dh), jnp.float32)
+        acc0 = jnp.zeros((b, h, chunk, dv), jnp.float32)
         m0 = jnp.full((b, h, chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, h, chunk), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(k_step, (acc0, m0, l0), (ks, vs, kps))
@@ -135,23 +138,23 @@ def _flash_fwd_impl(q, k, v, q_pos, k_pos, window, chunk):
         return None, (out.transpose(0, 2, 1, 3), lse)  # (B,C,H,dh),(B,H,C)
 
     _, (outs, lses) = jax.lax.scan(q_step, None, (qs, qps))
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, sq, h, dh)
+    out = jnp.moveaxis(outs, 0, 1).reshape(b, sq, h, dv)
     lse = jnp.moveaxis(lses, 0, 2).reshape(b, h, sq)
     return out, lse
 
 
-def _flash_fwd(q, k, v, q_pos, k_pos, window, chunk):
-    out, lse = _flash_fwd_impl(q, k, v, q_pos, k_pos, window, chunk)
+def _flash_fwd(q, k, v, q_pos, k_pos, window, chunk, scale):
+    out, lse = _flash_fwd_impl(q, k, v, q_pos, k_pos, window, chunk, scale)
     return out, (q, k, v, q_pos, k_pos, out, lse)
 
 
-def _flash_bwd(window, chunk, res, dout):
+def _flash_bwd(window, chunk, scale, res, dout):
     """FlashAttention-2 backward: recompute p per block from saved lse."""
     q, k, v, q_pos, k_pos, out, lse = res
     b, sq, h, dh = q.shape
+    v_dim = v.shape[-1]
     sk = k.shape[1]
     nq, nk = sq // chunk, sk // chunk
-    scale = 1.0 / np.sqrt(dh)
     delta = jnp.einsum("bshd,bshd->bhs",
                        dout.astype(jnp.float32), out.astype(jnp.float32))
 
@@ -206,21 +209,23 @@ def _flash_bwd(window, chunk, res, dout):
                                          qb.astype(jnp.float32))
             return (dk_acc, dv_acc), None
 
-        z = jnp.zeros((b, chunk, h, dh), jnp.float32)
-        (dkc, dvc), _ = jax.lax.scan(qstep, (z, z),
-                                     (qs, qps, dos, lses, deltas))
+        (dkc, dvc), _ = jax.lax.scan(
+            qstep, (jnp.zeros((b, chunk, h, dh), jnp.float32),
+                    jnp.zeros((b, chunk, h, v_dim), jnp.float32)),
+            (qs, qps, dos, lses, deltas))
         return None, (dkc, dvc)
 
     _, (dks, dvs) = jax.lax.scan(dkv_kstep, None, (ks, vs, kps))
     dk = jnp.moveaxis(dks, 0, 1).reshape(b, sk, h, dh).astype(k.dtype)
-    dv = jnp.moveaxis(dvs, 0, 1).reshape(b, sk, h, dh).astype(v.dtype)
+    dv = jnp.moveaxis(dvs, 0, 1).reshape(b, sk, h, v_dim).astype(v.dtype)
     return dq, dk, dv, None, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def sdpa_chunked(q, k, v, q_pos, k_pos, window: int, q_chunk: int):
+def sdpa_chunked(q, k, v, q_pos, k_pos, window: int, q_chunk: int,
+                 scale=None):
     """Memory-efficient (flash) attention: custom-VJP online softmax,
     O(chunk²) score memory in both passes; the path for long sequences
     where the Pallas kernel is not taken."""
@@ -240,7 +245,8 @@ def sdpa_chunked(q, k, v, q_pos, k_pos, window: int, q_chunk: int):
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         k_pos = jnp.concatenate(
             [k_pos, jnp.full((pad_k,), 2 ** 30, k_pos.dtype)])
-    out = _flash(q, k, v, q_pos, k_pos, window, q_chunk)
+    scale = 1.0 / np.sqrt(q.shape[-1]) if scale is None else scale
+    out = _flash(q, k, v, q_pos, k_pos, window, q_chunk, float(scale))
     return out[:, :sq]
 
 
@@ -256,18 +262,23 @@ def _unpartitioned() -> bool:
                for a, n in mesh.shape.items())
 
 
-def sdpa(q, k, v, q_pos, k_pos, spec: ModelSpec, window: int = 0):
-    """Causal attention core. On a TPU, self-attention over one shared
-    positions array (callers pass positions 0..S-1) runs the Pallas flash
-    kernel where its block sizes divide S; otherwise plain attention up
-    to ``spec.attn_full_seq_max`` positions, the chunked path beyond."""
+def sdpa(q, k, v, q_pos, k_pos, spec: ModelSpec, window: int = 0,
+         scale=None):
+    """Causal attention core; v may have its own head size, and the
+    scores are scaled by ``scale`` (default 1/sqrt of q's head size). On
+    a TPU, self-attention over one shared positions array (callers pass
+    positions 0..S-1) runs the Pallas flash kernel where its block sizes
+    divide S; otherwise plain attention up to ``spec.attn_full_seq_max``
+    positions, the chunked path beyond."""
     if on_tpu() and q_pos is k_pos and _unpartitioned() and \
             flash_attention.block_sizes(q.shape[1], q.shape[3]):
-        return flash_attention.flash_attention(q, k, v, window=window)
+        return flash_attention.flash_attention(q, k, v, window=window,
+                                               scale=scale)
     if q.shape[1] <= spec.attn_full_seq_max and \
             k.shape[1] <= spec.attn_full_seq_max:
-        return sdpa_full(q, k, v, q_pos, k_pos, window)
-    return sdpa_chunked(q, k, v, q_pos, k_pos, window, spec.attn_chunk)
+        return sdpa_full(q, k, v, q_pos, k_pos, window, scale)
+    return sdpa_chunked(q, k, v, q_pos, k_pos, window, spec.attn_chunk,
+                        scale)
 
 
 # ---------------------------------------------------------------------------
@@ -339,58 +350,71 @@ def gqa_decode(params, x, cache_k, cache_v, pos, spec: ModelSpec,
 # MLA — multi-head latent attention (DeepSeek-V2); latent KV cache
 # ---------------------------------------------------------------------------
 
-@jax.named_scope("attention")
-def mla_forward(params, x, positions, spec: ModelSpec):
-    """Full-sequence MLA (non-absorbed expansion). Returns (out, latents)
-    with latents = (c_kv, k_rope) for cache seeding."""
-    b, s, d = x.shape
-    h = spec.num_heads
-    r, rd, nd, vd = spec.kv_lora_rank, spec.qk_rope_dim, spec.qk_nope_dim, \
-        spec.v_head_dim
+def mla_scale(spec: ModelSpec) -> float:
+    """Softmax scale of MLA's scores: 1/sqrt(qk_nope + qk_rope), times
+    YaRN's mscale(factor, mscale_all_dim) squared where it is set."""
+    scale = 1.0 / np.sqrt(spec.qk_nope_dim + spec.qk_rope_dim)
+    y = spec.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= common.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return float(scale)
+
+
+def _mla_latent(params, x, positions, spec: ModelSpec):
+    """(q_nope, q_rope, normed latent c_kv, k_rope) of x (B, S, d); RoPE
+    (YaRN where the spec scales it) on the rope dims."""
+    b, s, _ = x.shape
+    h, r, rd, nd = spec.num_heads, spec.kv_lora_rank, spec.qk_rope_dim, \
+        spec.qk_nope_dim
     cd = spec.compute_dtype
     q = (x @ params["wq"].astype(cd)).reshape(b, s, h, nd + rd)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
-    q_rope = apply_rope(q_rope, positions, spec.rope_theta)
-
+    q_rope = apply_rope(q_rope, positions, spec.rope_theta,
+                        spec.rope_scaling)
     dkv = x @ params["wdkv"].astype(cd)                  # (B,S,r+rd)
-    c_kv, k_rope = dkv[..., :r], dkv[..., r:]
-    k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        spec.rope_theta)[:, :, 0, :]     # shared across heads
+    with jax.named_scope("kv_norm"):
+        c_kv = rmsnorm(dkv[..., :r], params["kv_norm"]["scale"])
+    k_rope = apply_rope(dkv[..., None, r:], positions, spec.rope_theta,
+                        spec.rope_scaling)[:, :, 0, :]   # shared across heads
+    return q_nope, q_rope, c_kv, k_rope
+
+
+@jax.named_scope("attention")
+def mla_forward(params, x, positions, spec: ModelSpec):
+    """Full-sequence MLA (non-absorbed expansion): q = [q_nope, q_rope]
+    and k = [k_nope, k_rope over every head], head size nope + rope, v of
+    ``v_head_dim``, through ``sdpa`` (the flash kernel on a TPU).
+    Returns (out, latents) with latents = (normed c_kv, k_rope) for cache
+    seeding."""
+    b, s, d = x.shape
+    h, nd, vd = spec.num_heads, spec.qk_nope_dim, spec.v_head_dim
+    cd = spec.compute_dtype
+    q_nope, q_rope, c_kv, k_rope = _mla_latent(params, x, positions, spec)
     k_nope = (c_kv @ params["wuk"].astype(cd)).reshape(b, s, h, nd)
     v = (c_kv @ params["wuv"].astype(cd)).reshape(b, s, h, vd)
-
-    scale = 1.0 / np.sqrt(nd + rd)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                  (b, s, h, k_rope.shape[-1]))], axis=-1)
+    pos = positions[0]
     with jax.named_scope("sdpa"):
-        sc = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
-              + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope)
-              ).astype(jnp.float32)
-        sc = sc * scale + _mask_bias(positions[0], positions[0], 0)
-        probs = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        out = sdpa(q, k, v, pos, pos, spec, scale=mla_scale(spec))
     out = out.reshape(b, s, h * vd) @ params["wo"].astype(cd)
     return out, (c_kv, k_rope)
 
 
 def mla_decode(params, x, cache_c, cache_kr, pos, spec: ModelSpec):
     """Absorbed-weight MLA decode: attention runs in the latent space so
-    the cache stores only (c_kv, k_rope) — (r + rd) per token instead of
-    2*h*hd. This is DeepSeek-V2's inference-time memory optimization and
-    the reason the arch can run `long_500k`."""
+    the cache stores only (normed c_kv, k_rope) — (r + rd) per token
+    instead of 2*h*hd. This is DeepSeek-V2's inference-time memory
+    optimization and the reason the arch can run `long_500k`."""
     b = x.shape[0]
     h = spec.num_heads
-    r, rd, nd, vd = spec.kv_lora_rank, spec.qk_rope_dim, spec.qk_nope_dim, \
-        spec.v_head_dim
+    r, nd, vd = spec.kv_lora_rank, spec.qk_nope_dim, spec.v_head_dim
     cd = spec.compute_dtype
     smax = cache_c.shape[1]
-    q = (x @ params["wq"].astype(cd)).reshape(b, 1, h, nd + rd)
-    q_nope, q_rope = q[..., :nd], q[..., nd:]
     pos_arr = jnp.full((b, 1), pos, jnp.int32)
-    q_rope = apply_rope(q_rope, pos_arr, spec.rope_theta)
-
-    dkv = x @ params["wdkv"].astype(cd)
-    c_new, kr_new = dkv[..., :r], dkv[..., r:]
-    kr_new = apply_rope(kr_new[:, :, None, :], pos_arr,
-                        spec.rope_theta)[:, :, 0, :]
+    q_nope, q_rope, c_new, kr_new = _mla_latent(params, x, pos_arr, spec)
     slot = jnp.minimum(pos, smax - 1)
     cache_c = jax.lax.dynamic_update_slice_in_dim(cache_c, c_new, slot, 1)
     cache_kr = jax.lax.dynamic_update_slice_in_dim(cache_kr, kr_new, slot, 1)
@@ -400,7 +424,7 @@ def mla_decode(params, x, cache_c, cache_kr, pos, spec: ModelSpec):
     q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, wuk)    # (B,1,H,r)
     sc = (jnp.einsum("bqhr,bkr->bhqk", q_lat, cache_c)
           + jnp.einsum("bqhd,bkd->bhqk", q_rope, cache_kr)
-          ).astype(jnp.float32) / np.sqrt(nd + rd)
+          ).astype(jnp.float32) * mla_scale(spec)
     valid = jnp.arange(smax)[None, :] <= pos
     sc = jnp.where(valid[None, None, :], sc, NEG_INF)
     probs = jax.nn.softmax(sc, axis=-1).astype(cache_c.dtype)

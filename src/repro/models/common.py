@@ -11,6 +11,21 @@ import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN RoPE scaling (DeepSeek-V2's ``rope_scaling`` of type yarn):
+    frequencies blended between interpolated (÷ ``factor``) and original
+    by a linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position`` positions;
+    ``mscale``/``mscale_all_dim`` scale cos/sin and the softmax."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelSpec:
     """Architecture hyper-parameters. One instance per config file."""
     name: str
@@ -25,6 +40,7 @@ class ModelSpec:
     mlp_type: str = "swiglu"       # swiglu | geglu | gelu
     norm_type: str = "rmsnorm"     # rmsnorm | layernorm
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScaling] = None
     tie_embeddings: bool = False
     scale_embed: bool = False      # gemma-style sqrt(d_model) embed scaling
 
@@ -43,16 +59,24 @@ class ModelSpec:
     qk_nope_dim: int = 0
     v_head_dim: int = 0
 
-    # MoE
+    # MoE: the router scores all ``num_experts``; this chip holds the
+    # routed experts [expert_first, expert_first + experts_held)
     num_experts: int = 0
     num_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
     first_dense_layers: int = 0
     dense_d_ff: int = 0            # d_ff of the leading dense layers
-    capacity_factor: float = 1.25
+    expert_first: int = 0
+    experts_held: int = 0          # 0 -> all num_experts
+    norm_topk_prob: bool = True    # renormalise the top-k weights
+    router_aux: str = "switch"     # switch (global batch) | seq (DeepSeek)
     router_aux_weight: float = 0.01
-    moe_group_size: int = 4096     # tokens per GShard dispatch group
+    # The loss's gradient reaches the router through the top-k weights;
+    # False stops it there, leaving the router to the aux loss.  For a
+    # chip that holds some experts and exchanges no tokens: its held
+    # experts alone would give that gradient, pulling tokens to them.
+    gate_grad: bool = True
 
     # SSM (Mamba2)
     ssm_state: int = 0
@@ -90,6 +114,10 @@ class ModelSpec:
         return self.head_dim or self.d_model // self.num_heads
 
     @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def padded_vocab(self) -> int:
         """Embedding-table rows: vocab rounded up to a multiple of 256 so
         the vocab dim shards evenly on the model axis (rows beyond
@@ -119,6 +147,7 @@ class ModelSpec:
         }
         if self.num_experts:
             r.update(num_experts=4, top_k=min(self.top_k, 2), moe_d_ff=64,
+                     expert_first=0, experts_held=0,
                      first_dense_layers=min(self.first_dense_layers, 1),
                      dense_d_ff=min(self.dense_d_ff, 256) if self.dense_d_ff else 0)
         if self.kv_lora_rank:
@@ -193,17 +222,56 @@ def norm_params(d: int, kind: str):
 # rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_frequencies(dim: int, theta: float):
-    return 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def _yarn_correction_dim(rotations, dim, theta, max_pos):
+    return dim * math.log(max_pos / (rotations * 2 * math.pi)) \
+        / (2 * math.log(theta))
+
+
+def rope_frequencies(dim: int, theta: float,
+                     scaling: Optional[YarnScaling] = None):
+    """Inverse frequencies of the ``dim // 2`` rotated pairs; under YaRN
+    (``DeepseekV2YarnRotaryEmbedding``) the pairs below the ``beta_fast``
+    correction dim keep theirs, those above the ``beta_slow`` one are
+    divided by ``factor``, and a linear ramp blends the two between."""
+    extra = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    if scaling is None:
+        return extra
+    m = scaling.original_max_position
+    low = max(math.floor(_yarn_correction_dim(scaling.beta_fast, dim, theta,
+                                              m)), 0)
+    high = min(math.ceil(_yarn_correction_dim(scaling.beta_slow, dim, theta,
+                                              m)), dim - 1)
+    ramp = (np.arange(dim // 2, dtype=np.float32) - low) / \
+        max(high - low, 0.001)
+    keep = 1.0 - np.clip(ramp, 0.0, 1.0)
+    return (extra / scaling.factor * (1.0 - keep) + extra * keep) \
+        .astype(np.float32)
+
+
+def rope_mscale(scaling: Optional[YarnScaling]) -> float:
+    """YaRN's factor on cos and sin (1 without scaling)."""
+    if scaling is None:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale) / \
+        yarn_mscale(scaling.factor, scaling.mscale_all_dim)
+
+
+def apply_rope(x, positions, theta: float,
+               scaling: Optional[YarnScaling] = None):
+    """x: (..., seq, heads, head_dim); positions: (..., seq); rotates the
+    two halves of each head."""
     dim = x.shape[-1]
-    freqs = jnp.asarray(rope_frequencies(dim, theta))       # (dim/2,)
+    freqs = jnp.asarray(rope_frequencies(dim, theta, scaling))  # (dim/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, dim/2)
-    cos = jnp.cos(angles)[..., None, :]
-    sin = jnp.sin(angles)[..., None, :]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    ms = rope_mscale(scaling)
+    if ms != 1.0:
+        cos, sin = cos * ms, sin * ms
+    cos, sin = cos[..., None, :], sin[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -82,8 +82,20 @@ def _seq_shard(x, spec: ModelSpec):
     return jax.lax.with_sharding_constraint(x, P(None, "model", None))
 
 
+_NO_MOE = {"aux": 0.0, "held_pairs": 0.0, "load_ratio": 0.0}
+
+
+def _merge_moe(acc, stats):
+    """Per-step MoE counters over layers: aux loss and held pairs summed,
+    the load ratio the largest of any layer."""
+    return {"aux": acc["aux"] + stats["aux"],
+            "held_pairs": acc["held_pairs"] + stats["held_pairs"],
+            "load_ratio": jnp.maximum(acc["load_ratio"],
+                                      stats["load_ratio"])}
+
+
 def _block_forward(lp, h, positions, spec: ModelSpec, is_moe: bool):
-    """One pre-norm block, full sequence. Returns (h, kv, aux)."""
+    """One pre-norm block, full sequence. Returns (h, kv, moe stats)."""
     h = _seq_shard(h, spec)
     a_in = norm(h, lp["ln1"], spec.norm_type)
     if spec.attention_type == "mla":
@@ -93,12 +105,11 @@ def _block_forward(lp, h, positions, spec: ModelSpec, is_moe: bool):
     h = _seq_shard(h + a_out, spec)
     m_in = norm(h, lp["ln2"], spec.norm_type)
     if is_moe:
-        m_out, aux, drop = moe_lib.moe_forward(lp["moe"], m_in, spec)
+        m_out, stats = moe_lib.moe_forward(lp["moe"], m_in, spec)
     else:
         m_out = mlp_forward(lp["mlp"], m_in, spec.mlp_type)
-        aux = jnp.zeros((), jnp.float32)
-        drop = jnp.zeros((), jnp.float32)
-    return h + m_out, kv, aux, drop
+        stats = {k: jnp.zeros((), jnp.float32) for k in _NO_MOE}
+    return h + m_out, kv, stats
 
 
 def _block_decode(lp, h, cache_layer, pos, spec: ModelSpec, is_moe: bool):
@@ -112,7 +123,7 @@ def _block_decode(lp, h, cache_layer, pos, spec: ModelSpec, is_moe: bool):
     h = h + a_out
     m_in = norm(h, lp["ln2"], spec.norm_type)
     if is_moe:
-        m_out, _, _ = moe_lib.moe_forward(lp["moe"], m_in, spec)
+        m_out, _ = moe_lib.moe_forward(lp["moe"], m_in, spec)
     else:
         m_out = mlp_forward(lp["mlp"], m_in, spec.mlp_type)
     return h + m_out, {"k": new_cache[0], "v": new_cache[1]}
@@ -148,40 +159,38 @@ def lm_logits(params, h, spec: ModelSpec):
 
 def forward(params, tokens, spec: ModelSpec, patches=None,
             collect_cache: bool = False):
-    """Returns (logits, cache|None, aux). tokens (B,S)."""
+    """Returns (logits, cache|None, moe stats). tokens (B,S)."""
     b = tokens.shape[0]
     h = embed_tokens(params, tokens, spec, patches=patches)
     s = h.shape[1]
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
 
     caches = []
-    aux_total = jnp.zeros((), jnp.float32)
-    drop_total = jnp.zeros((), jnp.float32)
+    stats = {k: jnp.asarray(v, jnp.float32) for k, v in _NO_MOE.items()}
+    block = _block_forward
+    if spec.remat:
+        # recompute block activations in the backward pass: trades ~1.3x
+        # block FLOPs for not streaming saved residuals through HBM
+        # (EXPERIMENTS.md §Perf C1); the dense prefix layers too
+        block = jax.checkpoint(_block_forward, static_argnums=(3, 4))
 
     if "prefix" in params:
         n_prefix = jax.tree_util.tree_leaves(params["prefix"])[0].shape[0]
         for i in range(n_prefix):
             lp = jax.tree_util.tree_map(lambda x: x[i], params["prefix"])
-            h, kv, aux, drop = _block_forward(lp, h, positions, spec, False)
+            h, kv, _ = block(lp, h, positions, spec, False)
             caches.append(kv)
-            aux_total += aux
 
     body_is_moe = spec.num_experts > 0
-    block = _block_forward
-    if spec.remat:
-        # recompute block activations in the backward pass: trades ~1.3x
-        # block FLOPs for not streaming saved residuals through HBM
-        # (EXPERIMENTS.md §Perf C1)
-        block = jax.checkpoint(_block_forward, static_argnums=(3, 4))
 
     def scan_body(carry, lp):
-        h, aux_acc, drop_acc = carry
-        h, kv, aux, drop = block(lp, h, positions, spec, body_is_moe)
+        h, acc = carry
+        h, kv, st = block(lp, h, positions, spec, body_is_moe)
         out = kv if collect_cache else None
-        return (h, aux_acc + aux, drop_acc + drop), out
+        return (h, _merge_moe(acc, st)), out
 
-    (h, aux_total, drop_total), body_kv = jax.lax.scan(
-        scan_body, (h, aux_total, drop_total), params["body"])
+    (h, stats), body_kv = jax.lax.scan(scan_body, (h, stats),
+                                       params["body"])
 
     h = norm(h, params["ln_f"], spec.norm_type)
     logits = lm_logits(params, h, spec)
@@ -189,7 +198,7 @@ def forward(params, tokens, spec: ModelSpec, patches=None,
     cache = None
     if collect_cache:
         cache = {"prefix": caches, "body": body_kv}
-    return logits, cache, {"aux": aux_total, "drop": drop_total}
+    return logits, cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -198,13 +207,20 @@ def forward(params, tokens, spec: ModelSpec, patches=None,
 
 def loss_fn(params, batch, spec: ModelSpec):
     patches = batch.get("patches")
-    logits, _, aux = forward(params, batch["tokens"], spec, patches=patches)
+    logits, _, moe = forward(params, batch["tokens"], spec, patches=patches)
     if patches is not None:
         logits = logits[:, patches.shape[1]:]       # only text positions
     with jax.named_scope("head"):
         loss = cross_entropy(logits, batch["labels"], batch.get("mask"))
-    total = loss + spec.router_aux_weight * aux["aux"]
-    return total, {"ce": loss, "aux": aux["aux"], "drop": aux["drop"]}
+    aux = spec.router_aux_weight * moe["aux"]
+    metrics = {"ce": loss, "aux": aux}
+    if spec.num_experts:
+        n_moe = spec.num_layers - spec.first_dense_layers
+        pairs = batch["tokens"].size * spec.top_k * n_moe
+        metrics.update(moe_held_pairs=moe["held_pairs"],
+                       moe_held_share=moe["held_pairs"] / pairs,
+                       moe_load_ratio=moe["load_ratio"])
+    return loss + aux, metrics
 
 
 # ---------------------------------------------------------------------------

@@ -224,3 +224,25 @@ def record_schedule(sched, registry: Optional[MetricsRegistry] = None) -> None:
         codec = getattr(st, "codec", "none") or "none"
         c.inc(st.wire_bytes, algorithm=st.algorithm, codec=codec)
         n.inc(1, algorithm=st.algorithm, codec=codec)
+
+
+def record_moe_step(metrics: Dict[str, float],
+                    registry: Optional[MetricsRegistry] = None) -> None:
+    """Mirror one step's MoE counters into the registry: the (token,
+    expert) pairs computed on the experts this chip holds, summed over
+    layers (a counter, so it sums over the steps recorded); their share
+    of all pairs, the largest held expert's load over the mean held load
+    (largest over layers) and the weighted aux loss (gauges, the last
+    step's).  A step of a model without experts records nothing."""
+    if "moe_held_pairs" not in metrics:
+        return
+    reg = registry if registry is not None else REGISTRY
+    reg.counter("moe_held_pairs",
+                help="(token, expert) pairs computed on held experts"
+                ).inc(float(metrics["moe_held_pairs"]))
+    for name, help in (("moe_held_share", "held pairs over all pairs"),
+                       ("moe_load_ratio",
+                        "largest held expert's load over the mean"),
+                       ("aux", "router load-balance loss, weighted")):
+        reg.gauge(name, help=help).set(float(metrics[name]))
+

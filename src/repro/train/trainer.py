@@ -8,6 +8,7 @@ from typing import Any, Callable, Optional
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.checkpoint import restore, save
 from repro.core import AggregatorConfig
 from repro.models import ModelApi
@@ -70,6 +71,8 @@ class Trainer:
             if (step + 1) % self.cfg.log_every == 0 or \
                     step == self.cfg.steps - 1:
                 m = {k: float(v) for k, v in metrics.items()}
+                if telemetry.enabled():
+                    telemetry.metrics.record_moe_step(m)
                 m["step"] = step + 1
                 if step > start_step:
                     dt = time.perf_counter() - t0

@@ -41,8 +41,26 @@ def test_special_fields():
     assert z.ssm_state == 64 and z.family == "hybrid"
     g = get_spec("gemma-7b")
     assert g.head_dim == 256 and g.mlp_type == "geglu"
+    # DeepSeek-V2-Lite's config.json: softmax router, top-6 weights as
+    # they are, per-sequence aux loss at 0.001, YaRN x40 over 4096
+    # positions (mscale = mscale_all_dim = 0.707), RMSNorm on the latent
+    assert not ds.norm_topk_prob and ds.router_aux == "seq"
+    assert ds.router_aux_weight == 0.001
+    y = ds.rope_scaling
+    assert (y.factor, y.original_max_position, y.beta_fast,
+            y.beta_slow) == (40.0, 4096, 32.0, 1.0)
+    assert y.mscale == y.mscale_all_dim == 0.707
+    assert ds.held_experts == 64 and ds.expert_first == 0
+    from repro.models.attention import mla_params
+    import jax
+    shapes = jax.eval_shape(lambda k: mla_params(k, ds),
+                            jax.random.PRNGKey(0))
+    assert shapes["kv_norm"]["scale"].shape == (512,)
     gm = get_spec("granite-moe-1b-a400m")
     assert gm.num_experts == 32 and gm.top_k == 8
+    # granite-moe keeps its renormalised top-k and the switch loss
+    assert gm.norm_topk_prob and gm.router_aux == "switch"
+    assert gm.rope_scaling is None
     w = get_spec("whisper-tiny")
     assert w.encoder_layers == 4 and w.encoder_seq == 1500
     x = get_spec("xlstm-350m")

@@ -1,7 +1,11 @@
 """Decode correctness: prefill + step-by-step decode must reproduce the
 full-forward logits at every generated position, for every arch family.
-MoE archs use a no-drop capacity factor (token dropping legitimately
-breaks causal equivalence — GShard semantics)."""
+The MoE layer drops no token, so MoE archs are held to the same
+equivalence, computed in float32: greedy top-k routing is discontinuous,
+and the absorbed-latent decode rounds differently from the expanded
+forward, so in bfloat16 a near tie between two experts can flip (seen:
+probabilities 0.2715 and 0.2695 trading places) and move the logits by a
+whole expert's output."""
 import dataclasses
 
 import jax
@@ -18,7 +22,7 @@ from repro.models import build_model, encdec, hybrid, ssm_lm, transformer
 def test_decode_matches_forward(arch):
     spec = get_spec(arch).reduced()
     if spec.num_experts:
-        spec = dataclasses.replace(spec, capacity_factor=8.0)
+        spec = dataclasses.replace(spec, dtype="float32")
     model = build_model(spec)
     key = jax.random.PRNGKey(0)
     params = model.init(key)

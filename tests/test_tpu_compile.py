@@ -115,3 +115,20 @@ def test_flash_attention_compiles(one_chip, b, s, h, kv, dh):
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert name in txt
     assert txt.count("tpu_custom_call") == 3
+
+
+def test_flash_attention_compiles_at_mla_shape(one_chip):
+    """deepseek-v2-lite's attention at 2 x 8192: q and k of head size
+    192, v of 128, YaRN's scale; the blocks its (8192, 192) entry picks."""
+    b, s, h = 2, 8192, 16
+    q = _sds((b, s, h, 192), jnp.bfloat16, one_chip)
+    v = _sds((b, s, h, 128), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: fa.flash_attention(
+            q, k, v, scale=0.1147, interpret=False), q, k, v)
+        return out, vjp(do)
+    txt = _compile_text(fwd_bwd, q, q, v, v)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in txt
+    assert txt.count("tpu_custom_call") == 3
